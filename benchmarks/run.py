@@ -19,6 +19,8 @@ def main() -> None:
                     help="comma-separated subset of: " + ",".join(BENCHES))
     args = ap.parse_args()
     selected = args.only.split(",") if args.only else BENCHES
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

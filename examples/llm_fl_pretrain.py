@@ -29,7 +29,7 @@ from repro.data import SeedAddressedBatcher
 from repro.models import init_params, train_loss
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b")
     ap.add_argument("--rounds", type=int, default=6)
@@ -40,7 +40,7 @@ def main():
     ap.add_argument("--d-model", type=int, default=256)
     ap.add_argument("--engine", default="event",
                     choices=["event", "cohort", "device"])
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = reduced(get_config(args.arch), n_layers=args.layers,
                   d_model=args.d_model, vocab=2048)
@@ -75,6 +75,7 @@ def main():
           f"wall {time.time()-t0:.1f}s")
     print(f"eval loss {loss0:.3f} -> {loss1:.3f}")
     assert loss1 < loss0, "loss should decrease"
+    return {"loss0": loss0, "loss1": loss1, "result": res, "sim": sim}
 
 
 if __name__ == "__main__":

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.analysis.salts import NOISE_SALT
 from repro.cohort.state import (FRAC_BITS, DeviceCohortState,
@@ -96,7 +97,7 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                    plan, dp_clip: float, dp_sigma: float,
                    dp_round_clip: float, use_dp_kernel: bool,
                    interpret: bool, in_kernel_rng: bool,
-                   fuse_ticks: bool, seed: int, strategy):
+                   fuse_ticks: bool, seed: int, strategy, mesh=None):
     """Compile the eval-boundary segment runner for one configuration.
 
     Returns ``segment(state, etas, sizes, accrual, target_k, tick_limit)``
@@ -104,7 +105,8 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
     ``server_k >= target_k`` or the tick budget runs out.  Per-instance
     arrays (etas, sizes, accrual) are arguments rather than closure
     constants so fresh engine instances with the same geometry reuse the
-    compiled executable.
+    compiled executable.  ``mesh`` is the client mesh when C is sharded
+    over several devices: the fused client kernels then run per shard.
     """
     dp_on = dp_sigma > 0.0 or dp_round_clip > 0.0
     noise_scale = dp_clip * dp_sigma
@@ -207,7 +209,8 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                 # expression the host engine jits in _make_strat_apply;
                 # here the weights feed the kernel as an operand
                 dec = ring_decay(strategy, st.server_k, R)
-                v = bucket_apply(st.v, kvec_due, dec, has_arrivals)
+                v = bucket_apply(st.v, kvec_due, dec, has_arrivals,
+                                 mesh=mesh)
                 buf_vec, buf_cnt = st.buf_vec, st.buf_cnt
             elif buffered:
                 # FedBuff: bank the due bucket, flush (and reset) on
@@ -217,14 +220,15 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                                     st.buf_vec + arr_due, st.buf_vec)
                 buf_cnt = st.buf_cnt + jnp.sum(cnt_total)
                 flush = buf_cnt >= BUF
-                v = bucket_apply(st.v, buf_vec[None, :], ones1, flush)
+                v = bucket_apply(st.v, buf_vec[None, :], ones1, flush,
+                                 mesh=mesh)
                 buf_vec = jnp.where(flush,
                                     jnp.zeros((D,), jnp.float32),
                                     buf_vec)
                 buf_cnt = jnp.where(flush, 0, buf_cnt)
             else:
                 v = bucket_apply(st.v, arr_due[None, :], ones1,
-                                 has_arrivals)
+                                 has_arrivals, mesh=mesh)
                 buf_vec, buf_cnt = st.buf_vec, st.buf_cnt
             upd_vec = st.upd_vec.at[slot].set(
                 jnp.zeros((D,), jnp.float32))
@@ -277,7 +281,8 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                 # fused gather+receive (kernels/tick_fused): the ring
                 # gather and the masked ISRRECEIVE in one [C, D] pass;
                 # CPU reference = bc_v[best] - eta*U verbatim
-                w = tick_deliver(st.w, st.U, bc_v, best, take, eta)
+                w = tick_deliver(st.w, st.U, bc_v, best, take, eta,
+                                 mesh=mesh)
                 return w, jnp.where(take, best_k, st.k)
 
             w, k = lax.cond(jnp.any(elig), do_deliver,
@@ -346,7 +351,7 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                         U, nk, eta * done.astype(jnp.float32), done,
                         clip=dp_round_clip, noise_scale=noise_scale,
                         use_kernel=use_dp_kernel, interpret=interpret,
-                        in_kernel_rng=in_kernel_rng)
+                        in_kernel_rng=in_kernel_rng, mesh=mesh)
                     sent = noised
                 else:
                     sent = U
@@ -386,7 +391,8 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                                  for m in masks])                  # [G, C]
                 any_g = jnp.stack([jnp.any(m) for m in masks])     # [G]
                 w, U, rows = tick_scatter(sent, w, U, rows, wgt,
-                                          any_g, done, eta, dp_on=dp_on)
+                                          any_g, done, eta, dp_on=dp_on,
+                                          mesh=mesh)
                 if stratified:
                     upd_kvec = rows.reshape((L, R, D))
                 else:
@@ -680,8 +686,15 @@ class DeviceCohortEngine:
 
         self.mesh = cohort_mesh()
         self._shardings = cohort_shardings(self.mesh, C)
+        # the fused client kernels need the mesh only when C is sharded
+        self._kernel_mesh = (self.mesh if self._shardings["w"].spec[0]
+                             else None)
         self.state = self._init_state()
-        self._etas_dev = jnp.asarray(self.etas, jnp.float32)
+        # replicated operands are committed to every device of the mesh:
+        # a steady segment may not move them (transfer guard below)
+        self._replicated = NamedSharding(self.mesh, PartitionSpec())
+        self._etas_dev = jax.device_put(
+            np.asarray(self.etas, np.float32), self._replicated)
         self._sizes_dev = jax.device_put(
             jnp.asarray(self.sizes, jnp.int32), self._shardings["w"])
         self._accrual_dev = jax.device_put(
@@ -738,7 +751,7 @@ class DeviceCohortEngine:
                self._plan.fingerprint(), self.dp_clip, self.dp_sigma,
                self.dp_round_clip, self.use_dp_kernel, self.interpret,
                self.dp_rng, self.fuse_ticks, self.seed,
-               self.strategy.fingerprint())
+               self.strategy.fingerprint(), self._kernel_mesh)
         cache = getattr(self.ctask, "_segment_fns", None)
         if cache is None:
             cache = self.ctask._segment_fns = {}
@@ -754,7 +767,7 @@ class DeviceCohortEngine:
                 interpret=self.interpret,
                 in_kernel_rng=(self.dp_rng == "in_kernel"),
                 fuse_ticks=self.fuse_ticks, seed=self.seed,
-                strategy=self.strategy)
+                strategy=self.strategy, mesh=self._kernel_mesh)
         return fn
 
     @property
@@ -803,8 +816,8 @@ class DeviceCohortEngine:
             # scalar segment bounds are committed to device OUTSIDE the
             # transfer guard below — the guarded steady dispatch must
             # see device-resident operands only
-            tgt = jnp.int32(target)
-            lim = jnp.int32(max_ticks)
+            tgt = jax.device_put(np.int32(target), self._replicated)
+            lim = jax.device_put(np.int32(max_ticks), self._replicated)
             with timer.phase("first_segment" if first_segment
                              else "steady"):
                 if first_segment:

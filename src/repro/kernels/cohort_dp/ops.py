@@ -1,10 +1,14 @@
 """Jitted wrapper for the cohort clip+noise kernel, with padding.
 
-``cohort_clip_noise`` pads the client axis to the f32 sublane multiple and
-the model axis to the lane-block multiple, generates the Gaussian noise
-(operand path) or derives an in-kernel PRNG seed (TPU path) from a jax
-key, and unpads.  Padded rows carry mask 0 / weight 0, so they pass
-through as zeros and contribute nothing to the aggregate.
+``cohort_clip_noise`` pads the client axis to the client tile and the
+model axis to the lane tile (``repro.kernels.tiling``), generates the
+Gaussian noise (operand path) or derives an in-kernel PRNG seed (TPU
+path) from a jax key, and unpads.  Padded rows carry mask 0 / weight 0,
+so they pass through as zeros and contribute nothing to the aggregate.
+With a ``mesh`` the kernel runs per client shard and the shards'
+aggregates are summed (``psum`` over ``clients``); the operand noise is
+drawn over the whole (C, D) block first, so it does not depend on the
+sharding.
 """
 from __future__ import annotations
 
@@ -12,19 +16,48 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.cohort_dp.kernel import (cohort_clip_noise_kernel,
                                             cohort_clip_noise_prng_kernel)
 from repro.kernels.cohort_dp.ref import cohort_clip_noise_ref
+from repro.kernels.tiling import CLIENTS, pad_to, per_client_shards, tiles
+
+
+def _local(u, noise, seed, wgt, mask, *, clip, noise_scale, d_block,
+           interpret, in_kernel_rng, sharded):
+    C, D = u.shape
+    c_block, Cp, d_block, Dp = tiles(C, D, d_block=d_block,
+                                     interpret=interpret)
+    u = pad_to(u, Cp, Dp)
+    wgt = pad_to(wgt[:, None], Cp, 1)
+    mask = pad_to(mask[:, None], Cp, 1)
+    if in_kernel_rng:
+        shard = (jax.lax.axis_index(CLIENTS) if sharded
+                 else jnp.int32(0))
+        out, agg = cohort_clip_noise_prng_kernel(
+            u, jnp.stack([seed, shard]).astype(jnp.int32), wgt, mask,
+            clip=clip, noise_scale=noise_scale, c_block=c_block,
+            d_block=d_block)
+    else:
+        out, agg = cohort_clip_noise_kernel(
+            u, pad_to(noise, Cp, Dp), wgt, mask, clip=clip,
+            noise_scale=noise_scale, c_block=c_block, d_block=d_block,
+            interpret=interpret)
+    agg = agg[0, :D]
+    if sharded:
+        agg = jax.lax.psum(agg, CLIENTS)
+    return out[:C, :D], agg
 
 
 @functools.partial(jax.jit, static_argnames=("clip", "noise_scale",
                                              "d_block", "use_kernel",
-                                             "interpret", "in_kernel_rng"))
+                                             "interpret", "in_kernel_rng",
+                                             "mesh"))
 def cohort_clip_noise(u, key, weights, mask, *, clip: float = 0.0,
                       noise_scale: float = 0.0, d_block: int = 128,
                       use_kernel: bool = True, interpret=None,
-                      in_kernel_rng: bool = False):
+                      in_kernel_rng: bool = False, mesh=None):
     """u: (C, D) round updates -> (noised rows (C, D), weighted agg (D,)).
 
     clip <= 0 disables the per-row norm clip (example-granularity DP clips
@@ -39,15 +72,6 @@ def cohort_clip_noise(u, key, weights, mask, *, clip: float = 0.0,
     C, D = u.shape
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    if interpret and not in_kernel_rng:
-        # CPU/interpret path has no 128-lane constraint: shrink the tile
-        # to the model dim's power-of-two so a small D (e.g. the paper's
-        # logreg, D=33) is not padded 4x.  The engines call this inside
-        # their jitted tick, so the saving is per completion tick.
-        p = 8
-        while p < D:
-            p <<= 1
-        d_block = min(d_block, p)
     u = u.astype(jnp.float32)
     mask_f = mask.astype(jnp.float32)
     wgt = weights.astype(jnp.float32)
@@ -58,22 +82,13 @@ def cohort_clip_noise(u, key, weights, mask, *, clip: float = 0.0,
     if not use_kernel:
         return cohort_clip_noise_ref(u, noise, wgt, mask_f, clip=clip,
                                      noise_scale=noise_scale)
-
-    pad_c = (-C) % 8
-    pad_d = (-D) % d_block
-    if pad_c or pad_d:
-        u = jnp.pad(u, ((0, pad_c), (0, pad_d)))
-        noise = jnp.pad(noise, ((0, pad_c), (0, pad_d)))
-        mask_f = jnp.pad(mask_f, (0, pad_c))
-        wgt = jnp.pad(wgt, (0, pad_c))
-    if in_kernel_rng:
-        seed = jax.random.randint(key, (1,), 0, jnp.iinfo(jnp.int32).max,
-                                  jnp.int32)
-        out, agg = cohort_clip_noise_prng_kernel(
-            u, seed, wgt, mask_f, clip=clip, noise_scale=noise_scale,
-            d_block=d_block)
-    else:
-        out, agg = cohort_clip_noise_kernel(
-            u, noise, wgt, mask_f, clip=clip, noise_scale=noise_scale,
-            d_block=d_block, interpret=interpret)
-    return out[:C, :D], agg[:D]
+    seed = (jax.random.randint(key, (), 0, jnp.iinfo(jnp.int32).max,
+                               jnp.int32) if in_kernel_rng
+            else jnp.int32(0))
+    cl, row = P(CLIENTS, None), P(CLIENTS)
+    local = functools.partial(
+        _local, clip=clip, noise_scale=noise_scale, d_block=d_block,
+        interpret=interpret,
+        in_kernel_rng=in_kernel_rng, sharded=mesh is not None)
+    return per_client_shards(local, mesh, (cl, cl, P(), row, row),
+                             (cl, P()))(u, noise, seed, wgt, mask_f)

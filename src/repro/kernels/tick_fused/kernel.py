@@ -8,13 +8,14 @@ forces the three-way split; within each kernel the gather, the bucket
 reduction, and the ring scatter fuse with their masks and stepsize
 scaling so the [C, D] traffic is a single HBM pass.
 
-Grid: D tiles only (``grid=(nd,)``).  The client axis is deliberately
-NOT tiled — every reduction over clients keeps the engines' historical
-full-axis ``jnp.sum`` order, which the bitwise host-vs-device parity
-contract pins.  Ring axes (B broadcast slots, G scatter rows) are
-small powers of two and unroll as Python loops: the broadcast gather
-is a select-accumulate (pure selection, no float sums, bitwise equal
-to ``bc_v[best]``) and each scatter row is a static store.
+Layout (``repro.kernels.tiling``): per-client scalars and masks are
+``(C, 1)`` columns, server vectors ``(1, D)`` rows, and the client
+kernels run on a ``(D tiles, C tiles)`` grid with C innermost.  The
+scatter's ring sums accumulate across C tiles in a resident output
+block, so they add within a tile first and then tile by tile.  Ring
+axes (B broadcast slots, G scatter rows) are small and unroll as Python
+loops: the broadcast gather is a select-accumulate (pure selection, no
+float sums, bitwise equal to ``bc_v[best]``).
 """
 from __future__ import annotations
 
@@ -23,6 +24,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# D tiles are independent; the C axis revisits the resident ring-sum
+# output block, so it runs in order
+_CLIENT_GRID = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
+
+
+def _col(ref, shape):
+    """A ``(rows, 1)`` column block broadcast across the lanes."""
+    return jnp.broadcast_to(ref[...], shape)
 
 
 def _bucket_apply_kernel(v_ref, rows_ref, dec_ref, flag_ref, out_ref, *,
@@ -33,133 +45,130 @@ def _bucket_apply_kernel(v_ref, rows_ref, dec_ref, flag_ref, out_ref, *,
     if single:
         # size-1 bucket: scale-by-weight, not a sum — jnp.sum over a
         # size-1 axis computes 0.0 + x and flips a -0.0 row
-        contrib = rows[0] * dec[0]
+        contrib = rows[0:1] * dec[0:1]
     else:
-        contrib = jnp.sum(rows * dec[:, None], axis=0)
-    out_ref[...] = jnp.where(flag_ref[0] != 0, v - contrib, v)
+        contrib = jnp.sum(rows * dec, axis=0, keepdims=True)
+    out_ref[...] = jnp.where(_col(flag_ref, v.shape) != 0, v - contrib, v)
 
 
 def _tick_deliver_kernel(w_ref, u_ref, bc_ref, best_ref, take_ref,
                          eta_ref, out_ref, *, B: int):
     w = w_ref[...]
-    bc = bc_ref[...]
-    best = best_ref[...]
+    best = _col(best_ref, w.shape)
     gathered = jnp.zeros_like(w)
     for b in range(B):
-        gathered = jnp.where((best == b)[:, None], bc[b][None, :],
-                             gathered)
-    take = take_ref[...] != 0
-    out_ref[...] = jnp.where(take[:, None],
-                             gathered - eta_ref[...][:, None] * u_ref[...],
-                             w)
+        gathered = jnp.where(best == b, bc_ref[b:b + 1, :], gathered)
+    out_ref[...] = jnp.where(_col(take_ref, w.shape) != 0,
+                             gathered - eta_ref[...] * u_ref[...], w)
 
 
-def _tick_scatter_kernel(sent_ref, w_ref, u_ref, upd_ref, wgt_ref,
-                         any_ref, done_ref, eta_ref, w_out, u_out,
-                         upd_out, *, G: int, dp_on: bool):
+def _tick_scatter_kernel(sent_ref, w_ref, u_ref, wgt_ref, done_ref,
+                         eta_ref, w_out, u_out, vec_out, *, G: int,
+                         dp_on: bool):
+    c = pl.program_id(1)
     sent = sent_ref[...]
     wgt = wgt_ref[...]
-    any_g = any_ref[...]
-    upd = upd_ref[...]
     for g in range(G):
-        vec = jnp.sum(sent * wgt[g][:, None], axis=0)
-        upd_out[g, :] = jnp.where(any_g[g] != 0, upd[g] + vec, upd[g])
-    done = done_ref[...] != 0
+        part = jnp.sum(sent * wgt[:, g:g + 1], axis=0, keepdims=True)
+
+        # the first C tile stores, later tiles add: no 0.0 + x term
+        @pl.when(c == 0)
+        def _store(g=g, part=part):
+            vec_out[g:g + 1, :] = part
+
+        @pl.when(c > 0)
+        def _add(g=g, part=part):
+            vec_out[g:g + 1, :] += part
+
+    done = _col(done_ref, sent.shape) != 0
     if dp_on:
         w_out[...] = jnp.where(
-            done[:, None],
-            w_ref[...] + eta_ref[...][:, None] * (sent - u_ref[...]),
+            done, w_ref[...] + eta_ref[...] * (sent - u_ref[...]),
             w_ref[...])
     else:
         w_out[...] = w_ref[...]
-    u_out[...] = jnp.where(done[:, None], jnp.zeros_like(sent), sent)
+    u_out[...] = jnp.where(done, jnp.zeros_like(sent), sent)
 
 
-def bucket_apply_kernel(v, rows, dec, flag, *, d_block: int = 512,
+def bucket_apply_kernel(v, rows, dec, flag, *, d_block: int,
                         interpret: bool = True):
-    """v: (D,), rows: (A, D), dec: (A,), flag: (1,) int32; D % d_block == 0.
-
-    Returns the updated server vector (D,).
+    """v: (1, D), rows: (A, D), dec: (A, 1), flag: (1, 1) int32;
+    D % d_block == 0.  Returns the updated server row (1, D).
     """
     A, D = rows.shape
     assert D % d_block == 0, (D, d_block)
-    nd = D // d_block
     return pl.pallas_call(
         functools.partial(_bucket_apply_kernel, single=(A == 1)),
-        grid=(nd,),
+        name="bucket_apply",
+        grid=(D // d_block,),
         in_specs=[
-            pl.BlockSpec((d_block,), lambda d: (d,)),
+            pl.BlockSpec((1, d_block), lambda d: (0, d)),
             pl.BlockSpec((A, d_block), lambda d: (0, d)),
-            pl.BlockSpec((A,), lambda d: (0,)),
-            pl.BlockSpec((1,), lambda d: (0,)),
+            pl.BlockSpec((A, 1), lambda d: (0, 0)),
+            pl.BlockSpec((1, 1), lambda d: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((d_block,), lambda d: (d,)),
-        out_shape=jax.ShapeDtypeStruct((D,), jnp.float32),
+        out_specs=pl.BlockSpec((1, d_block), lambda d: (0, d)),
+        out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
         interpret=interpret,
     )(v, rows, dec, flag)
 
 
-def tick_deliver_kernel(w, U, bc_v, best, take, eta, *,
-                        d_block: int = 512, interpret: bool = True):
-    """w, U: (C, D); bc_v: (B, D); best, take: (C,) int32; eta: (C,).
+def tick_deliver_kernel(w, U, bc_v, best, take, eta, *, c_block: int,
+                        d_block: int, interpret: bool = True):
+    """w, U: (C, D); bc_v: (B, D); best, take: (C, 1) int32;
+    eta: (C, 1).  C % c_block == 0, D % d_block == 0.
 
-    C % 8 == 0, D % d_block == 0.  Returns the updated weights (C, D).
+    Returns the updated weights (C, D).
     """
     C, D = w.shape
     B = bc_v.shape[0]
-    assert D % d_block == 0, (D, d_block)
-    nd = D // d_block
+    assert C % c_block == 0 and D % d_block == 0, (C, c_block, D, d_block)
+    blk = pl.BlockSpec((c_block, d_block), lambda d, c: (c, d))
+    col = pl.BlockSpec((c_block, 1), lambda d, c: (c, 0))
     return pl.pallas_call(
         functools.partial(_tick_deliver_kernel, B=B),
-        grid=(nd,),
-        in_specs=[
-            pl.BlockSpec((C, d_block), lambda d: (0, d)),
-            pl.BlockSpec((C, d_block), lambda d: (0, d)),
-            pl.BlockSpec((B, d_block), lambda d: (0, d)),
-            pl.BlockSpec((C,), lambda d: (0,)),
-            pl.BlockSpec((C,), lambda d: (0,)),
-            pl.BlockSpec((C,), lambda d: (0,)),
-        ],
-        out_specs=pl.BlockSpec((C, d_block), lambda d: (0, d)),
+        name="tick_deliver",
+        grid=(D // d_block, C // c_block),
+        in_specs=[blk, blk,
+                  pl.BlockSpec((B, d_block), lambda d, c: (0, d)),
+                  col, col, col],
+        out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((C, D), jnp.float32),
+        compiler_params=_CLIENT_GRID,
         interpret=interpret,
     )(w, U, bc_v, best, take, eta)
 
 
-def tick_scatter_kernel(sent, w, U, upd, wgt, any_g, done, eta, *,
-                        dp_on: bool, d_block: int = 512,
+def tick_scatter_kernel(sent, w, U, wgt, done, eta, *, dp_on: bool,
+                        c_block: int, d_block: int,
                         interpret: bool = True):
-    """sent, w, U: (C, D); upd: (G, D); wgt: (G, C); any_g: (G,) int32;
-    done: (C,) int32; eta: (C,).  C % 8 == 0, D % d_block == 0.
+    """sent, w, U: (C, D); wgt: (C, G) per-client ring-row weights;
+    done: (C, 1) int32; eta: (C, 1).  C % c_block == 0,
+    D % d_block == 0.
 
-    Returns (w_new (C, D), U_new (C, D), upd_new (G, D)).
+    Returns (w_new (C, D), U_new (C, D), vec (G, D)), where
+    ``vec[g] = sum_c wgt[c, g] * sent[c]`` — the caller adds it to ring
+    row g under its any-arrival guard.
     """
     C, D = sent.shape
-    G = upd.shape[0]
-    assert D % d_block == 0, (D, d_block)
-    nd = D // d_block
+    G = wgt.shape[1]
+    assert C % c_block == 0 and D % d_block == 0, (C, c_block, D, d_block)
+    blk = pl.BlockSpec((c_block, d_block), lambda d, c: (c, d))
+    col = pl.BlockSpec((c_block, 1), lambda d, c: (c, 0))
     return pl.pallas_call(
         functools.partial(_tick_scatter_kernel, G=G, dp_on=dp_on),
-        grid=(nd,),
-        in_specs=[
-            pl.BlockSpec((C, d_block), lambda d: (0, d)),
-            pl.BlockSpec((C, d_block), lambda d: (0, d)),
-            pl.BlockSpec((C, d_block), lambda d: (0, d)),
-            pl.BlockSpec((G, d_block), lambda d: (0, d)),
-            pl.BlockSpec((G, C), lambda d: (0, 0)),
-            pl.BlockSpec((G,), lambda d: (0,)),
-            pl.BlockSpec((C,), lambda d: (0,)),
-            pl.BlockSpec((C,), lambda d: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((C, d_block), lambda d: (0, d)),
-            pl.BlockSpec((C, d_block), lambda d: (0, d)),
-            pl.BlockSpec((G, d_block), lambda d: (0, d)),
-        ],
+        name="tick_scatter",
+        grid=(D // d_block, C // c_block),
+        in_specs=[blk, blk, blk,
+                  pl.BlockSpec((c_block, G), lambda d, c: (c, 0)),
+                  col, col],
+        out_specs=[blk, blk,
+                   pl.BlockSpec((G, d_block), lambda d, c: (0, d))],
         out_shape=[
             jax.ShapeDtypeStruct((C, D), jnp.float32),
             jax.ShapeDtypeStruct((C, D), jnp.float32),
             jax.ShapeDtypeStruct((G, D), jnp.float32),
         ],
+        compiler_params=_CLIENT_GRID,
         interpret=interpret,
-    )(sent, w, U, upd, wgt, any_g, done, eta)
+    )(sent, w, U, wgt, done, eta)

@@ -4,16 +4,24 @@ Backend-aware dispatch: ``use_kernel=None`` (the engine default) means
 "kernel on TPU/GPU, pure-jnp reference on CPU", and ``interpret=None``
 means "infer interpret mode from ``jax.default_backend()``".  On CPU
 the reference path therefore traces the exact expressions the device
-engine historically inlined — the golden fixtures and host-vs-device
-parity stay byte-identical by construction — while an accelerator
-backend runs the fused kernels unpadded-equivalently.
+engine historically inlined, so the golden fixtures and host-vs-device
+parity stay byte-identical by construction.  An accelerator backend runs
+the compiled kernels, whose contract against the reference is: the
+delivery gather and every mask/select exact, each elementwise
+multiply-add within one rounding of its product (it may be fused), the
+sums (ring scatter, stratified bucket apply) within the reduction-order
+bound the README's parity table states.
 
-Kernel-path padding: C to the f32 sublane multiple (8), D to the lane
-block.  Padded clients carry weight/mask/take 0 and padded model lanes
-are zero, so they are sliced off unchanged.  (Known accepted hazard:
-zero-padded client rows append ``+0.0`` terms to the scatter sums,
-which could flip an exactly ``-0.0`` total; the CPU parity path is
-unpadded and numpy comparisons treat the two zeros as equal.)
+Kernel-path padding (``repro.kernels.tiling``): C to the client tile, D
+to the lane tile.  Padded clients carry weight/mask/take 0 and padded
+model lanes are zero, so they are sliced off unchanged.
+
+``mesh``: when the engine shards the client axis over several devices,
+the client kernels run per shard under ``shard_map`` — a ``pallas_call``
+cannot be partitioned by the compiler — and the ring sums of the
+scatter are then summed across shards (``psum`` over ``clients``), the
+one float collective of the tick.  The bucket apply touches replicated
+server vectors only; under a mesh every device runs it on its copy.
 """
 from __future__ import annotations
 
@@ -21,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.tick_fused.kernel import (bucket_apply_kernel,
                                              tick_deliver_kernel,
@@ -28,6 +37,7 @@ from repro.kernels.tick_fused.kernel import (bucket_apply_kernel,
 from repro.kernels.tick_fused.ref import (bucket_apply_ref,
                                           tick_deliver_ref,
                                           tick_scatter_ref)
+from repro.kernels.tiling import CLIENTS, pad_to, per_client_shards, tiles
 
 
 def _resolve(use_kernel, interpret):
@@ -38,72 +48,97 @@ def _resolve(use_kernel, interpret):
     return use_kernel, interpret
 
 
-def _shrink(d_block: int, D: int) -> int:
-    # interpret path has no 128-lane constraint: shrink the tile to the
-    # model dim's power-of-two (min 8) so a small D is not padded
-    # many-fold
-    return min(d_block, max(8, 1 << (D - 1).bit_length()))
+def _col(x, Cp, dtype):
+    """[C] per-client scalars -> padded (Cp, 1) kernel column."""
+    return pad_to(x.astype(dtype)[:, None], Cp, 1)
+
+
+def _bucket_local(v, rows, dec, flag, *, d_block, interpret):
+    A, D = rows.shape
+    _, _, d_block, Dp = tiles(1, D, d_block=d_block, interpret=interpret)
+    out = bucket_apply_kernel(
+        pad_to(v.astype(jnp.float32)[None, :], 1, Dp),
+        pad_to(rows.astype(jnp.float32), A, Dp),
+        dec.astype(jnp.float32)[:, None],
+        jnp.asarray(flag, jnp.int32).reshape((1, 1)),
+        d_block=d_block, interpret=interpret)
+    return out[0, :D]
 
 
 @functools.partial(jax.jit, static_argnames=("d_block", "use_kernel",
-                                             "interpret"))
+                                             "interpret", "mesh"))
 def bucket_apply(v, rows, dec, flag, *, d_block: int = 512,
-                 use_kernel=None, interpret=None):
+                 use_kernel=None, interpret=None, mesh=None):
     """v: (D,), rows: (A, D), dec: (A,), flag: scalar bool -> (D,)."""
     use_kernel, interpret = _resolve(use_kernel, interpret)
     if not use_kernel:
         return bucket_apply_ref(v, rows, dec, flag)
-    D = v.shape[0]
-    if interpret:
-        d_block = _shrink(d_block, D)
-    v = v.astype(jnp.float32)
-    rows = rows.astype(jnp.float32)
-    pad_d = (-D) % d_block
-    if pad_d:
-        v = jnp.pad(v, (0, pad_d))
-        rows = jnp.pad(rows, ((0, 0), (0, pad_d)))
-    flag_i = jnp.asarray(flag, jnp.int32).reshape((1,))
-    out = bucket_apply_kernel(v, rows, dec.astype(jnp.float32), flag_i,
-                              d_block=d_block, interpret=interpret)
-    return out[:D]
+    # replicated operands: every device applies the same bucket
+    local = functools.partial(_bucket_local, d_block=d_block,
+                              interpret=interpret)
+    return per_client_shards(local, mesh, (P(),) * 4, P())(
+        v, rows, dec, flag)
+
+
+def _deliver_local(w, U, bc_v, best, take, eta, *, d_block, interpret):
+    C, D = w.shape
+    B = bc_v.shape[0]
+    c_block, Cp, d_block, Dp = tiles(C, D, d_block=d_block,
+                                     interpret=interpret)
+    out = tick_deliver_kernel(
+        pad_to(w.astype(jnp.float32), Cp, Dp),
+        pad_to(U.astype(jnp.float32), Cp, Dp),
+        pad_to(bc_v.astype(jnp.float32), B, Dp),
+        _col(best, Cp, jnp.int32), _col(take, Cp, jnp.int32),
+        _col(eta, Cp, jnp.float32),
+        c_block=c_block, d_block=d_block, interpret=interpret)
+    return out[:C, :D]
 
 
 @functools.partial(jax.jit, static_argnames=("d_block", "use_kernel",
-                                             "interpret"))
+                                             "interpret", "mesh"))
 def tick_deliver(w, U, bc_v, best, take, eta, *, d_block: int = 512,
-                 use_kernel=None, interpret=None):
+                 use_kernel=None, interpret=None, mesh=None):
     """w, U: (C, D); bc_v: (B, D); best: (C,) int; take: (C,) bool;
     eta: (C,) -> updated weights (C, D)."""
     use_kernel, interpret = _resolve(use_kernel, interpret)
     if not use_kernel:
         return tick_deliver_ref(w, U, bc_v, best, take, eta)
-    C, D = w.shape
-    if interpret:
-        d_block = _shrink(d_block, D)
-    w = w.astype(jnp.float32)
-    U = U.astype(jnp.float32)
-    bc_v = bc_v.astype(jnp.float32)
-    best_i = best.astype(jnp.int32)
-    take_i = take.astype(jnp.int32)
-    eta = eta.astype(jnp.float32)
-    pad_c = (-C) % 8
-    pad_d = (-D) % d_block
-    if pad_c or pad_d:
-        w = jnp.pad(w, ((0, pad_c), (0, pad_d)))
-        U = jnp.pad(U, ((0, pad_c), (0, pad_d)))
-        bc_v = jnp.pad(bc_v, ((0, 0), (0, pad_d)))
-        best_i = jnp.pad(best_i, (0, pad_c))
-        take_i = jnp.pad(take_i, (0, pad_c))
-        eta = jnp.pad(eta, (0, pad_c))
-    out = tick_deliver_kernel(w, U, bc_v, best_i, take_i, eta,
-                              d_block=d_block, interpret=interpret)
-    return out[:C, :D]
+    cl, row = P(CLIENTS, None), P(CLIENTS)
+    local = functools.partial(_deliver_local, d_block=d_block,
+                              interpret=interpret)
+    return per_client_shards(local, mesh,
+                             (cl, cl, P(), row, row, row), cl)(
+        w, U, bc_v, best, take, eta)
+
+
+def _scatter_local(sent, w, U, wgt, done, eta, *, dp_on, d_block,
+                   interpret, sharded):
+    C, D = sent.shape
+    G = wgt.shape[0]
+    c_block, Cp, d_block, Dp = tiles(C, D, d_block=d_block,
+                                     interpret=interpret)
+    w_new, u_new, vec = tick_scatter_kernel(
+        pad_to(sent.astype(jnp.float32), Cp, Dp),
+        pad_to(w.astype(jnp.float32), Cp, Dp),
+        pad_to(U.astype(jnp.float32), Cp, Dp),
+        pad_to(wgt.astype(jnp.float32).T, Cp, G),
+        _col(done, Cp, jnp.int32), _col(eta, Cp, jnp.float32),
+        dp_on=dp_on, c_block=c_block, d_block=d_block,
+        interpret=interpret)
+    vec = vec[:, :D]
+    if sharded:
+        # the server bucket reduce: ring-row partial sums across shards
+        vec = jax.lax.psum(vec, CLIENTS)
+    return w_new[:C, :D], u_new[:C, :D], vec
 
 
 @functools.partial(jax.jit, static_argnames=("dp_on", "d_block",
-                                             "use_kernel", "interpret"))
+                                             "use_kernel", "interpret",
+                                             "mesh"))
 def tick_scatter(sent, w, U, upd, wgt, any_g, done, eta, *, dp_on: bool,
-                 d_block: int = 512, use_kernel=None, interpret=None):
+                 d_block: int = 512, use_kernel=None, interpret=None,
+                 mesh=None):
     """sent, w, U: (C, D); upd: (G, D); wgt: (G, C); any_g: (G,) bool;
     done: (C,) bool; eta: (C,)
     -> (w_new (C, D), U_new (C, D), upd_new (G, D))."""
@@ -111,28 +146,14 @@ def tick_scatter(sent, w, U, upd, wgt, any_g, done, eta, *, dp_on: bool,
     if not use_kernel:
         return tick_scatter_ref(sent, w, U, upd, wgt, any_g, done, eta,
                                 dp_on=dp_on)
-    C, D = sent.shape
-    if interpret:
-        d_block = _shrink(d_block, D)
-    sent = sent.astype(jnp.float32)
-    w = w.astype(jnp.float32)
-    U = U.astype(jnp.float32)
+    cl, row = P(CLIENTS, None), P(CLIENTS)
+    local = functools.partial(_scatter_local, dp_on=dp_on,
+                              d_block=d_block, interpret=interpret,
+                              sharded=mesh is not None)
+    w_new, u_new, vec = per_client_shards(
+        local, mesh, (cl, cl, cl, P(None, CLIENTS), row, row),
+        (cl, cl, P()))(sent, w, U, wgt, done, eta)
+    # guarded add: rows with no arrivals stay bitwise untouched
     upd = upd.astype(jnp.float32)
-    wgt = wgt.astype(jnp.float32)
-    any_i = any_g.astype(jnp.int32)
-    done_i = done.astype(jnp.int32)
-    eta = eta.astype(jnp.float32)
-    pad_c = (-C) % 8
-    pad_d = (-D) % d_block
-    if pad_c or pad_d:
-        sent = jnp.pad(sent, ((0, pad_c), (0, pad_d)))
-        w = jnp.pad(w, ((0, pad_c), (0, pad_d)))
-        U = jnp.pad(U, ((0, pad_c), (0, pad_d)))
-        upd = jnp.pad(upd, ((0, 0), (0, pad_d)))
-        wgt = jnp.pad(wgt, ((0, 0), (0, pad_c)))
-        done_i = jnp.pad(done_i, (0, pad_c))
-        eta = jnp.pad(eta, (0, pad_c))
-    w_new, u_new, upd_new = tick_scatter_kernel(
-        sent, w, U, upd, wgt, any_i, done_i, eta, dp_on=dp_on,
-        d_block=d_block, interpret=interpret)
-    return w_new[:C, :D], u_new[:C, :D], upd_new[:, :D]
+    upd_new = jnp.where((any_g != 0)[:, None], upd + vec, upd)
+    return w_new, u_new, upd_new

@@ -119,10 +119,19 @@ def param_shardings(mesh: Mesh, params_shape: Any) -> Any:
 # ---------------------------------------------------------------------------
 
 def cohort_mesh(devices=None) -> Mesh:
-    """1-D mesh over all local devices; axis ``clients`` shards the
-    population axis of the cohort engines' stacked state."""
-    devices = list(devices) if devices is not None else jax.devices()
-    return Mesh(np.asarray(devices), ("clients",))
+    """1-D mesh whose axis ``clients`` shards the population axis of the
+    cohort engines' stacked state.
+
+    ``devices`` defaults to the one device set with ``jax.default_device``
+    (so ``with jax.default_device(jax.devices()[0]):`` keeps an engine
+    built inside on one chip), else every local device.
+    """
+    if devices is None:
+        one = jax.config.jax_default_device
+        if isinstance(one, str):                 # a platform name
+            one = jax.devices(one)[0]
+        devices = [one] if one is not None else jax.devices()
+    return Mesh(np.asarray(list(devices)), ("clients",))
 
 
 def cohort_pspecs(mesh: Mesh, n_clients: int) -> Dict[str, P]:

@@ -47,6 +47,7 @@ far_messages``; ``bucket_applies <= ring_scatters + far_groups``;
 """
 from __future__ import annotations
 
+import re
 from typing import Dict, List, Mapping, Optional, Sequence, Union
 
 import numpy as np
@@ -233,3 +234,21 @@ def check_ops(ops: Mapping[str, int], *,
                 f"{get('block_ticks')} (an iteration's block came from "
                 f">= 1 block tick)")
     return problems
+
+
+# --- what a compiled program calls ------------------------------------------
+
+def pallas_kernels_in(compiled_text: str) -> set:
+    """Names of the Pallas kernels a compiled TPU program calls (its
+    ``tpu_custom_call``s), from ``Compiled.as_text()``."""
+    return set(re.findall(
+        r"%([A-Za-z_]+?)(?:\.\d+)? = [^\n]*custom_call_target="
+        r"\"tpu_custom_call\"", compiled_text))
+
+
+def collectives_in(compiled_text: str) -> list:
+    """``(result shape, kind)`` of each cross-device collective in a
+    compiled program, from ``Compiled.as_text()``."""
+    return re.findall(
+        r"= (\S+) (all-reduce|all-gather|reduce-scatter|all-to-all|"
+        r"collective-permute)(?:-start)?\(", compiled_text)

@@ -247,8 +247,8 @@ def test_table_assignment_draw_is_threefry_addressed_and_pinned():
     np.testing.assert_array_equal(ids, np.asarray(jit_ids))
     np.testing.assert_array_equal(ids, [0, 0, 0, 0, 1, 1, 0, 0])
     uni = TableAssignment("draw").resolve(12, 3, seed=0)
-    np.testing.assert_array_equal(uni, [1, 1, 2, 1, 2, 0, 0, 2, 2, 2,
-                                        1, 0])
+    np.testing.assert_array_equal(uni, [2, 0, 0, 1, 1, 2, 0, 0, 2, 1,
+                                        1, 1])
 
 
 def test_error_paths_tables_and_legacy_specs(tmp_path):

@@ -79,6 +79,77 @@ def test_tick_scatter_kernel_matches_ref(C, D, dp_on):
         np.testing.assert_array_equal(np.asarray(r), np.asarray(k))
 
 
+def _sum_bound(terms):
+    """Reduction-order bound of an f32 sum of n terms (README parity
+    table): 2 * n * 2^-24 * sum |terms|, per output element."""
+    a = np.abs(np.asarray(terms, np.float64))
+    return 2 * a.shape[0] * 2.0 ** -24 * a.sum(axis=0)
+
+
+def _assert_one_rounding(got, a, bc):
+    """``got`` is ``a + bc`` in f32, with the product ``bc`` rounded or
+    fused into a multiply-add: within 2 * 2^-24 * (|a| + |bc|) of the
+    float64 value."""
+    a, bc = np.asarray(a, np.float64), np.asarray(bc, np.float64)
+    np.testing.assert_array_less(np.abs(np.asarray(got) - (a + bc)),
+                                 2.0 ** -23 * (np.abs(a) + np.abs(bc))
+                                 + 1e-30)
+
+
+def test_client_tiled_kernels_within_reduction_order_bound():
+    """The compiled path tiles C; here four C tiles run in interpret
+    mode.  Selections stay exact; elementwise multiply-adds are within
+    one rounding of the product (a backend may fuse them), and the sums
+    over clients add tile by tile within their reduction-order bound of
+    the float64 sum."""
+    from repro.kernels.cohort_dp.kernel import cohort_clip_noise_kernel
+    from repro.kernels.tick_fused.kernel import (tick_deliver_kernel,
+                                                 tick_scatter_kernel)
+    C, D, G, B, tile = 32, 16, 3, 4, 8
+    ks = jax.random.split(jax.random.PRNGKey(7), 8)
+    sent, w, U, noise = (np.asarray(_rand(k, C, D)) for k in ks[:4])
+    wgt = np.asarray(jax.random.uniform(ks[4], (C, G), jnp.float32))
+    done = np.arange(C) % 3 != 0
+    eta = np.linspace(0.05, 0.1, C, dtype=np.float32)
+    col = lambda x, dt: jnp.asarray(x, dt)[:, None]            # noqa: E731
+    kw = dict(c_block=tile, d_block=8, interpret=True)
+    on = done[:, None]
+
+    w_new, u_new, vec = tick_scatter_kernel(
+        sent, w, U, wgt, col(done, jnp.int32), col(eta, jnp.float32),
+        dp_on=True, **kw)
+    np.testing.assert_array_equal(np.asarray(u_new),
+                                  np.where(on, 0.0, sent))
+    np.testing.assert_array_equal(np.asarray(w_new)[~done], w[~done])
+    _assert_one_rounding(np.asarray(w_new)[done], w[done],
+                         (eta[:, None] * (sent - U))[done])
+    for g in range(G):
+        terms = sent * wgt[:, g:g + 1]
+        np.testing.assert_array_less(
+            np.abs(np.asarray(vec[g]) - terms.astype(np.float64).sum(0)),
+            _sum_bound(terms) + 1e-30)
+
+    best = np.asarray(jax.random.randint(ks[5], (C,), 0, B))
+    bc_v = np.asarray(_rand(ks[6], B, D))
+    got = np.asarray(tick_deliver_kernel(
+        w, U, bc_v, col(best, jnp.int32), col(done, jnp.int32),
+        col(eta, jnp.float32), **kw))
+    np.testing.assert_array_equal(got[~done], w[~done])
+    _assert_one_rounding(got[done], bc_v[best][done],
+                         -(eta[:, None] * U)[done])
+
+    out, agg = cohort_clip_noise_kernel(
+        sent, noise, col(eta, jnp.float32), col(done, jnp.float32),
+        clip=0.0, noise_scale=0.5, **kw)
+    out = np.asarray(out)
+    np.testing.assert_array_equal(out[~done], sent[~done])
+    _assert_one_rounding(out[done], sent[done], 0.5 * noise[done])
+    terms = out * eta[:, None]
+    np.testing.assert_array_less(
+        np.abs(np.asarray(agg[0]) - terms.astype(np.float64).sum(0)),
+        _sum_bound(terms) + 1e-30)
+
+
 def test_empty_bucket_negative_zero_preserved():
     """Guarded adds: a flagged-off bucket apply and an empty scatter
     group must pass ``-0.0`` through bit-for-bit (the hazard that makes
@@ -129,21 +200,27 @@ def test_dp_rng_knob_validation():
                                   use_dp_kernel=False, **kw)
 
 
-def test_in_kernel_prng_noise_chi_square():
+@pytest.mark.parametrize("C,D", [(64, 512), (4096, 256)])
+def test_in_kernel_prng_noise_chi_square(C, D):
     """dp_rng='in_kernel' draws standard normals inside the kernel —
     distributionally equivalent to the operand path (chi-square over
-    normal-quantile bins), never bitwise.  TPU only by contract."""
+    normal-quantile bins), never bitwise.  At C=4096 the draw spans
+    several client tiles, each seeded on its own.  TPU only by
+    contract."""
     if jax.default_backend() != "tpu":
         pytest.skip("in-kernel PRNG path needs a TPU backend "
                     "(pltpu.prng_random_bits has no CPU/GPU lowering)")
     from repro.kernels.cohort_dp.ops import cohort_clip_noise
-    C, D = 64, 512
     u = jnp.zeros((C, D), jnp.float32)
     out, _ = cohort_clip_noise(
         u, jax.random.PRNGKey(5), jnp.ones((C,), jnp.float32),
         jnp.ones((C,), jnp.float32), clip=0.0, noise_scale=1.0,
         use_kernel=True, in_kernel_rng=True)
-    s = np.asarray(out).ravel()
+    out = np.asarray(out)
+    # no two client tiles (nor the halves of one) repeat a stream
+    half = C // 2
+    assert not np.array_equal(out[:half], out[half:])
+    s = out.ravel()
     assert abs(s.mean()) < 0.02 and abs(s.std() - 1.0) < 0.02
     edges = np.array([-2.0, -1.0, -0.5, 0.0, 0.5, 1.0, 2.0])
     cdf = np.vectorize(
@@ -189,3 +266,70 @@ def test_fuse_ticks_bitwise_and_iter_relations():
     # coalescing actually fires on the FedSGD-shaped workload (half of
     # its ticks are overhead-only, so they ride along)
     assert li_on < li_off
+
+
+# --- client axis sharded over several devices --------------------------------
+
+_SHARDED_SCRIPT = r"""
+import json, sys
+import jax, numpy as np
+import repro.kernels.tick_fused.ops as tick_ops
+# the fused kernels (interpret mode) in place of the CPU reference, so
+# their shard_map path runs
+tick_ops._resolve = lambda use_kernel, interpret: (True, True)
+from repro.cohort import DeviceCohortSimulator
+from repro.core import LogRegTask
+from repro.data import make_binary_dataset
+X, y = make_binary_dataset(512, 20, seed=0)
+out = {}
+for dp in (False, True):
+    task = LogRegTask(X, y, l2=1e-3, sample_seed=1,
+                      **(dict(dp_clip=0.1, dp_sigma=8.0) if dp else {}))
+    kw = dict(n_clients=64, sizes_per_client=[1] * 4,
+              round_stepsizes=[0.1] * 4, d=3 if dp else 1, seed=0,
+              block=1, scenario="mobile_diurnal" if dp else "uniform")
+    runs = []
+    for n in (4, 1):
+        if n == 1:
+            with jax.default_device(jax.devices()[0]):
+                sim = DeviceCohortSimulator(task, **kw)
+        else:
+            sim = DeviceCohortSimulator(task, **kw)
+        res = sim.run(max_rounds=4, eval_every=2)
+        tel = res["telemetry"]
+        runs.append(dict(
+            devices=int(sim.engine.mesh.devices.size),
+            census=[int(tel.messages), int(tel.broadcasts),
+                    {k: int(v) for k, v in tel.ops.items()},
+                    [int(x) for x in tel.staleness_hist]],
+            v=np.asarray(sim.engine.state.v).tolist()))
+    out[str(dp)] = runs
+print(json.dumps(out))
+"""
+
+
+def test_sharded_client_axis_matches_one_device():
+    """Four virtual CPU devices: the engine shards C, the fused kernels
+    run per shard under shard_map and the ring sums are psum'ed.  The
+    integer census equals the one-device run's; the model differs only
+    by the add order of the shards' partial sums.  (A child process:
+    the device count is fixed when JAX starts.)"""
+    import json
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    for dp, (four, one) in out.items():
+        assert (four["devices"], one["devices"]) == (4, 1), dp
+        assert four["census"] == one["census"], dp
+        v4, v1 = np.asarray(four["v"]), np.asarray(one["v"])
+        # 4 rounds, each a sum over 64 clients re-associated across 4
+        # shards: 4 * 2 * 64 * 2^-24 of the model's magnitude
+        tol = 4 * 2 * 64 * 2.0 ** -24 * max(1.0, np.abs(v1).max())
+        assert np.abs(v4 - v1).max() <= tol, (dp, np.abs(v4 - v1).max())
