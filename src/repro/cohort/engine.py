@@ -46,7 +46,7 @@ from repro.cohort.state import (FRAC_BITS, BroadcastRing, CohortState,
 from repro.core.strategies import get_strategy, ring_decay
 from repro.kernels.cohort_dp import cohort_clip_noise
 from repro.scenarios import get_scenario, scenario_plan
-from repro.telemetry import (STALE_BINS, PhaseTimer, build_report,
+from repro.telemetry import (STALE_BINS, SpanRecorder, build_report,
                              open_trace, staleness_bin, update_msg_bytes)
 from repro.telemetry.costs import (OP_BLOCK_TICKS, OP_BUCKET_APPLIES,
                                    OP_CASCADE_TICKS, OP_COMPLETE_TICKS,
@@ -466,7 +466,7 @@ class CohortEngine:
         next_eval = eval_every
         # kept on the engine so the timeline CLI (python -m
         # repro.telemetry capture) can export the wall spans after run()
-        timer = self.timer = PhaseTimer()
+        timer = self.timer = SpanRecorder()
         import time
         run_t0 = time.perf_counter()
         # First segment runs unguarded (jit compiles may stage host

@@ -40,9 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.protocol import BroadcastMsg, Client, Server, UpdateMsg
-from repro.telemetry import (STALE_BINS, PhaseTimer, broadcast_msg_bytes,
-                             build_report, model_flat_dim, open_trace,
-                             staleness_bin, update_msg_bytes)
+from repro.telemetry import (STALE_BINS, SpanRecorder,
+                             broadcast_msg_bytes, build_report,
+                             model_flat_dim, open_trace, staleness_bin,
+                             update_msg_bytes)
 
 
 @dataclass(order=True)
@@ -224,7 +225,7 @@ class AsyncFLSimulator:
         next_eval = eval_every
         # kept on the simulator so the timeline CLI (python -m
         # repro.telemetry capture) can export the wall spans after run()
-        timer = self.timer = PhaseTimer()
+        timer = self.timer = SpanRecorder()
         run_t0 = time.perf_counter()
         while self.events and self.server.k < max_rounds:
             ev = heapq.heappop(self.events)

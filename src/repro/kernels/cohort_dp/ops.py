@@ -46,7 +46,8 @@ def _local(u, noise, seed, wgt, mask, *, clip, noise_scale, d_block,
             interpret=interpret)
     agg = agg[0, :D]
     if sharded:
-        agg = jax.lax.psum(agg, CLIENTS)
+        with jax.named_scope("cohort.allreduce"):
+            agg = jax.lax.psum(agg, CLIENTS)
     return out[:C, :D], agg
 
 
@@ -79,16 +80,19 @@ def cohort_clip_noise(u, key, weights, mask, *, clip: float = 0.0,
                                                     and in_kernel_rng)
     noise = (jax.random.normal(key, (C, D), jnp.float32)
              if draw_operand_noise else jnp.zeros((C, D), jnp.float32))
-    if not use_kernel:
-        return cohort_clip_noise_ref(u, noise, wgt, mask_f, clip=clip,
-                                     noise_scale=noise_scale)
-    seed = (jax.random.randint(key, (), 0, jnp.iinfo(jnp.int32).max,
-                               jnp.int32) if in_kernel_rng
-            else jnp.int32(0))
-    cl, row = P(CLIENTS, None), P(CLIENTS)
-    local = functools.partial(
-        _local, clip=clip, noise_scale=noise_scale, d_block=d_block,
-        interpret=interpret,
-        in_kernel_rng=in_kernel_rng, sharded=mesh is not None)
-    return per_client_shards(local, mesh, (cl, cl, P(), row, row),
-                             (cl, P()))(u, noise, seed, wgt, mask_f)
+    # the wrapper's scope (repro.telemetry.scopes): the clip+noise with
+    # its pads and slices; the operand noise draw above stays outside
+    with jax.named_scope("cohort_clip_noise"):
+        if not use_kernel:
+            return cohort_clip_noise_ref(u, noise, wgt, mask_f, clip=clip,
+                                         noise_scale=noise_scale)
+        seed = (jax.random.randint(key, (), 0, jnp.iinfo(jnp.int32).max,
+                                   jnp.int32) if in_kernel_rng
+                else jnp.int32(0))
+        cl, row = P(CLIENTS, None), P(CLIENTS)
+        local = functools.partial(
+            _local, clip=clip, noise_scale=noise_scale, d_block=d_block,
+            interpret=interpret,
+            in_kernel_rng=in_kernel_rng, sharded=mesh is not None)
+        return per_client_shards(local, mesh, (cl, cl, P(), row, row),
+                                 (cl, P()))(u, noise, seed, wgt, mask_f)

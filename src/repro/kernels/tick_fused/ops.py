@@ -71,13 +71,14 @@ def bucket_apply(v, rows, dec, flag, *, d_block: int = 512,
                  use_kernel=None, interpret=None, mesh=None):
     """v: (D,), rows: (A, D), dec: (A,), flag: scalar bool -> (D,)."""
     use_kernel, interpret = _resolve(use_kernel, interpret)
-    if not use_kernel:
-        return bucket_apply_ref(v, rows, dec, flag)
-    # replicated operands: every device applies the same bucket
-    local = functools.partial(_bucket_local, d_block=d_block,
-                              interpret=interpret)
-    return per_client_shards(local, mesh, (P(),) * 4, P())(
-        v, rows, dec, flag)
+    with jax.named_scope("bucket_apply"):
+        if not use_kernel:
+            return bucket_apply_ref(v, rows, dec, flag)
+        # replicated operands: every device applies the same bucket
+        local = functools.partial(_bucket_local, d_block=d_block,
+                                  interpret=interpret)
+        return per_client_shards(local, mesh, (P(),) * 4, P())(
+            v, rows, dec, flag)
 
 
 def _deliver_local(w, U, bc_v, best, take, eta, *, d_block, interpret):
@@ -102,14 +103,15 @@ def tick_deliver(w, U, bc_v, best, take, eta, *, d_block: int = 512,
     """w, U: (C, D); bc_v: (B, D); best: (C,) int; take: (C,) bool;
     eta: (C,) -> updated weights (C, D)."""
     use_kernel, interpret = _resolve(use_kernel, interpret)
-    if not use_kernel:
-        return tick_deliver_ref(w, U, bc_v, best, take, eta)
-    cl, row = P(CLIENTS, None), P(CLIENTS)
-    local = functools.partial(_deliver_local, d_block=d_block,
-                              interpret=interpret)
-    return per_client_shards(local, mesh,
-                             (cl, cl, P(), row, row, row), cl)(
-        w, U, bc_v, best, take, eta)
+    with jax.named_scope("tick_deliver"):
+        if not use_kernel:
+            return tick_deliver_ref(w, U, bc_v, best, take, eta)
+        cl, row = P(CLIENTS, None), P(CLIENTS)
+        local = functools.partial(_deliver_local, d_block=d_block,
+                                  interpret=interpret)
+        return per_client_shards(local, mesh,
+                                 (cl, cl, P(), row, row, row), cl)(
+            w, U, bc_v, best, take, eta)
 
 
 def _scatter_local(sent, w, U, wgt, done, eta, *, dp_on, d_block,
@@ -129,7 +131,8 @@ def _scatter_local(sent, w, U, wgt, done, eta, *, dp_on, d_block,
     vec = vec[:, :D]
     if sharded:
         # the server bucket reduce: ring-row partial sums across shards
-        vec = jax.lax.psum(vec, CLIENTS)
+        with jax.named_scope("cohort.allreduce"):
+            vec = jax.lax.psum(vec, CLIENTS)
     return w_new[:C, :D], u_new[:C, :D], vec
 
 
@@ -143,17 +146,19 @@ def tick_scatter(sent, w, U, upd, wgt, any_g, done, eta, *, dp_on: bool,
     done: (C,) bool; eta: (C,)
     -> (w_new (C, D), U_new (C, D), upd_new (G, D))."""
     use_kernel, interpret = _resolve(use_kernel, interpret)
-    if not use_kernel:
-        return tick_scatter_ref(sent, w, U, upd, wgt, any_g, done, eta,
-                                dp_on=dp_on)
-    cl, row = P(CLIENTS, None), P(CLIENTS)
-    local = functools.partial(_scatter_local, dp_on=dp_on,
-                              d_block=d_block, interpret=interpret,
-                              sharded=mesh is not None)
-    w_new, u_new, vec = per_client_shards(
-        local, mesh, (cl, cl, cl, P(None, CLIENTS), row, row),
-        (cl, cl, P()))(sent, w, U, wgt, done, eta)
-    # guarded add: rows with no arrivals stay bitwise untouched
+    with jax.named_scope("tick_scatter"):
+        if not use_kernel:
+            return tick_scatter_ref(sent, w, U, upd, wgt, any_g, done, eta,
+                                    dp_on=dp_on)
+        cl, row = P(CLIENTS, None), P(CLIENTS)
+        local = functools.partial(_scatter_local, dp_on=dp_on,
+                                  d_block=d_block, interpret=interpret,
+                                  sharded=mesh is not None)
+        w_new, u_new, vec = per_client_shards(
+            local, mesh, (cl, cl, cl, P(None, CLIENTS), row, row),
+            (cl, cl, P()))(sent, w, U, wgt, done, eta)
+    # guarded add, outside the wrapper's scope (ring-bucket work, not
+    # the kernel's layout): rows with no arrivals stay bitwise untouched
     upd = upd.astype(jnp.float32)
     upd_new = jnp.where((any_g != 0)[:, None], upd + vec, upd)
     return w_new, u_new, upd_new

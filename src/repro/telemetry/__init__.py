@@ -1,6 +1,6 @@
 """Telemetry: communication census, staleness/participation metrics,
 per-client DP accounting, JSONL traces, the in-loop op census, and
-span-based profiling with Perfetto timeline export — one
+span-based profiling with Perfetto timeline export, device scopes — one
 ``MetricsReport`` schema shared by all three engines."""
 from repro.telemetry.costs import (
     N_OPS, OP_NAMES, check_ops, cost_decomposition, ops_dict, ops_vector,
@@ -11,9 +11,9 @@ from repro.telemetry.report import (
     build_report, model_flat_dim, participation_sizes, staleness_bin,
     update_msg_bytes,
 )
+from repro.telemetry.scopes import DEVICE_SCOPES, op_scopes
 from repro.telemetry.spans import (
-    PhaseTimer, SpanRecorder, trace_to_perfetto, validate_trace_events,
-    write_perfetto,
+    SpanRecorder, trace_to_perfetto, validate_trace_events, write_perfetto,
 )
 from repro.telemetry.trace import JsonlTraceWriter, open_trace
 
@@ -22,8 +22,9 @@ __all__ = [
     "build_report", "model_flat_dim", "participation_sizes",
     "staleness_bin", "update_msg_bytes",
     "JsonlTraceWriter", "open_trace",
-    "PhaseTimer", "SpanRecorder", "trace_to_perfetto",
+    "SpanRecorder", "trace_to_perfetto",
     "validate_trace_events", "write_perfetto",
+    "DEVICE_SCOPES", "op_scopes",
     "N_OPS", "OP_NAMES", "check_ops", "cost_decomposition", "ops_dict",
     "ops_vector", "zero_ops",
 ]

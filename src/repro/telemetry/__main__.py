@@ -4,9 +4,13 @@ Two modes:
 
   * ``capture``: run a small engine workload end-to-end and write a
     Perfetto-loadable trace JSON combining BOTH clocks — the engine's
-    wall-clock phase spans (compile/steady/eval, from ``SpanRecorder``)
-    and the virtual-protocol timeline reconstructed from the run's JSONL
-    trace (message lifecycles / eval segments with op-census counters).
+    wall-clock ``cohort.*`` spans (``SpanRecorder``: for the device
+    engine ``cohort.engine_init`` with its children, ``cohort.compile``,
+    each segment's ``cohort.dispatch`` / ``cohort.sync``,
+    ``cohort.eval``, ``cohort.report``; the same spans a
+    ``jax.profiler`` capture records) and the virtual-protocol timeline
+    reconstructed from the run's JSONL trace (message lifecycles / eval
+    segments with op-census counters).
 
       PYTHONPATH=src python -m repro.telemetry capture --out trace.json
 

@@ -37,6 +37,7 @@ census counters still agree).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 from dataclasses import dataclass, field
@@ -189,13 +190,14 @@ def build_report(*, engine: str, clients: int, flat_dim: int, rounds: int,
                  n_examples: Optional[int] = None,
                  sizes_per_client: Optional[Sequence[Sequence[int]]] = None,
                  wall: Optional[Dict[str, float]] = None,
-                 ops=None) -> MetricsReport:
+                 ops=None, spans=None) -> MetricsReport:
     """Assemble a MetricsReport from raw engine counters.
 
     Derives bytes_down (every fired broadcast reaches the whole fleet)
     and, when ``dp_sigma > 0`` and the dataset size is known, the
     per-client DP accounting rows from the rounds each client actually
-    contributed.
+    contributed; ``spans`` (a ``SpanRecorder``) times that accounting
+    as ``cohort.dp_accounting``.
     """
     ub = update_msg_bytes(flat_dim)
     bb = broadcast_msg_bytes(flat_dim)
@@ -206,8 +208,11 @@ def build_report(*, engine: str, clients: int, flat_dim: int, rounds: int,
     if (dp_sigma > 0 and n_examples is not None
             and sizes_per_client is not None and len(sizes_per_client)):
         from repro.dp.accountant import per_client_accounting
-        rows = participation_sizes(sizes_per_client, part)
-        dp_rows = per_client_accounting(rows, n_examples, dp_sigma, dp_delta)
+        with (spans.phase("cohort.dp_accounting") if spans is not None
+              else contextlib.nullcontext()):
+            rows = participation_sizes(sizes_per_client, part)
+            dp_rows = per_client_accounting(rows, n_examples, dp_sigma,
+                                            dp_delta)
     return MetricsReport(
         engine=engine, clients=int(clients), flat_dim=int(flat_dim),
         rounds=int(rounds), messages=int(messages),

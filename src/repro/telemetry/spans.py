@@ -1,10 +1,15 @@
 """Span recording + Chrome/Perfetto trace-event export, on dual clocks.
 
-``SpanRecorder`` subsumes the old ``PhaseTimer``: it still accumulates
-named wall-clock phases for ``MetricsReport.wall`` / BENCH_cohort.json
-(now exporting the re-entry *counts* alongside the seconds), but it also
-keeps every individual span — (name, track, start, duration) — so a run
-can be rendered as a timeline instead of a histogram.
+``SpanRecorder`` accumulates named wall-clock spans for
+``MetricsReport.wall`` / BENCH_cohort.json (seconds and re-entry
+counts), and keeps every individual span — (name, track, start,
+duration) — so a run can be rendered as a timeline instead of a
+histogram.  Each span is also bracketed with
+``jax.profiler.TraceAnnotation``, a no-op unless a profiler capture is
+running, so the program's spans (``cohort.engine_init``,
+``cohort.compile``, ``cohort.dispatch``, ``cohort.sync``,
+``cohort.eval``, ``cohort.report``, ...) sit on the device trace's
+clock next to the device ops they caused.
 
 Export targets the Chrome trace-event JSON the Perfetto UI loads
 (https://ui.perfetto.dev, legacy JSON importer): complete ``"X"`` slices
@@ -12,10 +17,8 @@ for engine phases and eval segments, instant ``"i"`` + flow ``"s"``/
 ``"f"`` + async ``"b"``/``"e"`` events for message lifecycles.  Two
 clocks coexist as two trace *processes*:
 
-  * **wall** — real seconds from the recorder's epoch (compile/warmup/
-    steady/eval engine phases, optionally bracketed with
-    ``jax.profiler.TraceAnnotation`` so the same names show up inside an
-    XLA profile);
+  * **wall** — real seconds from the recorder's epoch (the engines'
+    phase spans);
   * **virtual protocol seconds** — reconstructed from the PR 6 JSONL
     trace (``repro.telemetry.trace``): the event sim's per-message
     records become send→apply / broadcast→deliver flow arrows, the
@@ -35,31 +38,32 @@ from contextlib import contextmanager
 from typing import (Any, Dict, IO, Iterable, List, Optional, Sequence,
                     Union)
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
-    "SpanRecorder", "PhaseTimer", "trace_to_perfetto",
-    "validate_trace_events", "write_perfetto",
+    "SpanRecorder", "trace_to_perfetto", "validate_trace_events",
+    "write_perfetto",
 ]
 
 
 class SpanRecorder:
-    """Accumulating phase timer that also keeps the span timeline.
+    """Accumulating span timer that also keeps the span timeline.
 
-    ``phases``/``counts``/``as_dict`` keep the PhaseTimer contract
-    (every engine's ``MetricsReport.wall`` is built from them);
-    ``spans`` holds one entry per ``phase()``/``add()`` with start times
-    relative to the recorder's epoch (the first recorded instant), and
+    ``phases``/``counts``/``as_dict`` accumulate per span name (every
+    engine's ``MetricsReport.wall`` is built from them); ``spans`` holds
+    one entry per ``phase()``/``add()`` with start times relative to the
+    recorder's epoch (the first recorded instant), and
     ``to_trace_events`` renders them as Perfetto slices — one thread
     track per phase name, so re-entrant phases stay non-overlapping per
     track (invariant INV-SPAN).
     """
 
-    def __init__(self, *, annotate: bool = False):
+    def __init__(self):
         self.phases: Dict[str, float] = {}
         self.counts: Dict[str, int] = {}
         # (name, track, t0_s, dur_s, args) — t0 relative to epoch
         self.spans: List[Dict[str, Any]] = []
         self.epoch: Optional[float] = None
-        self._annotate = bool(annotate)
 
     # -- recording --------------------------------------------------------
     def _now(self) -> float:
@@ -72,27 +76,13 @@ class SpanRecorder:
     def phase(self, name: str, *, track: Optional[str] = None,
               **args: Any):
         t0 = self._now()
-        ann = None
-        if self._annotate:
-            # bracket the span in the XLA profiler's timeline too, when
-            # a jax.profiler trace is being captured around this run
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
         try:
-            yield
+            # the same span in the profiler's host timeline, when a
+            # jax.profiler capture is running (a no-op otherwise)
+            with TraceAnnotation(name):
+                yield
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
-            dur = self._now() - t0
-            self._record(name, track, t0, dur, args)
-
-    # old PhaseTimer users call phase(); span() is the forward-looking
-    # alias the timeline docs use
-    span = phase
+            self._record(name, track, t0, self._now() - t0, args)
 
     def add(self, name: str, seconds: float, *,
             track: Optional[str] = None, **args: Any) -> None:
@@ -109,12 +99,17 @@ class SpanRecorder:
                                dur=dur, args=dict(args)))
 
     # -- aggregates (MetricsReport.wall / BENCH_cohort.json) --------------
-    def as_dict(self, suffix: str = "_s") -> Dict[str, float]:
-        """Accumulated seconds per phase (``<name>_s``) AND how many
-        spans fed each accumulation (``<name>_n``)."""
-        out: Dict[str, float] = {
-            f"{k}{suffix}": v for k, v in self.phases.items()}
-        out.update({f"{k}_n": n for k, n in self.counts.items()})
+    def as_dict(self, suffix: str = "_s", since: int = 0
+                ) -> Dict[str, float]:
+        """Seconds per span name (``<name>_s``) AND how many spans fed
+        each (``<name>_n``), over the spans recorded from index
+        ``since`` of ``spans`` on (one run of an engine that records
+        several)."""
+        out: Dict[str, float] = {}
+        for s in self.spans[since:]:
+            k = s["name"]
+            out[f"{k}{suffix}"] = out.get(f"{k}{suffix}", 0.0) + s["dur"]
+            out[f"{k}_n"] = out.get(f"{k}_n", 0) + 1
         return out
 
     # -- timeline export --------------------------------------------------
@@ -127,10 +122,6 @@ class SpanRecorder:
                     ts_us=s["t0"] * 1e6, dur_us=s["dur"] * 1e6,
                     args=s["args"])
         return b.events
-
-
-class PhaseTimer(SpanRecorder):
-    """Backwards-compatible name: a SpanRecorder (see base docstring)."""
 
 
 class _EventBuilder:

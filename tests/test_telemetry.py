@@ -23,7 +23,7 @@ from repro.data import make_binary_dataset
 from repro.dp import moments_epsilon, per_client_accounting
 from repro.scenarios import LatencyTable, Scenario
 from repro.telemetry import (HEADER_BYTES, OP_NAMES, STALE_BINS,
-                             JsonlTraceWriter, MetricsReport, PhaseTimer,
+                             JsonlTraceWriter, MetricsReport,
                              SpanRecorder, build_report, check_ops,
                              cost_decomposition, model_flat_dim,
                              participation_sizes, staleness_bin,
@@ -286,8 +286,8 @@ def test_model_flat_dim_counts_pytree_scalars():
     assert model_flat_dim({"w": np.zeros((3, 4)), "b": np.zeros(())}) == 13
 
 
-def test_phase_timer_accumulates():
-    t = PhaseTimer()
+def test_span_recorder_accumulates():
+    t = SpanRecorder()
     with t.phase("a"):
         pass
     with t.phase("a"):
@@ -296,10 +296,12 @@ def test_phase_timer_accumulates():
         pass
     assert t.counts["a"] == 2 and t.counts["b"] == 1
     d = t.as_dict()
-    # seconds per phase plus span counts (SpanRecorder.as_dict)
+    # seconds per span name plus span counts
     assert set(d) == {"a_s", "b_s", "a_n", "b_n"}
     assert all(v >= 0 for v in d.values())
     assert d["a_n"] == 2 and d["b_n"] == 1
+    # from a later span on: only what was recorded since
+    assert t.as_dict(since=2) == {"b_s": t.spans[2]["dur"], "b_n": 1}
 
 
 def test_engine_reports_carry_wall_phases():
@@ -308,7 +310,14 @@ def test_engine_reports_carry_wall_phases():
               round_stepsizes=[0.1, 0.08], d=1, seed=0)
     r_dv = DeviceCohortSimulator(task, block=4, scenario="uniform",
                                  **kw).run(max_rounds=2)
-    assert "first_segment_s" in r_dv["telemetry"].wall
+    wall = r_dv["telemetry"].wall
+    assert "first_segment_s" in wall
+    # construction, the compile of the segment, each segment's dispatch
+    # and sync, and the segment-cache misses
+    for k in ("engine_init", "pad_sizes", "scenario_plan", "init_state",
+              "compile", "dispatch", "sync", "eval"):
+        assert wall[f"{k}_s"] >= 0 and wall[f"{k}_n"] >= 1, k
+    assert wall["compiles"] == 1 and wall["dispatch_n"] == wall["sync_n"]
     r_ev = AsyncFLSimulator(task, scenario="uniform", **kw).run(max_rounds=2)
     assert r_ev["telemetry"].wall["run_s"] > 0
 

@@ -302,18 +302,36 @@ for dp in (False, True):
             census=[int(tel.messages), int(tel.broadcasts),
                     {k: int(v) for k, v in tel.ops.items()},
                     [int(x) for x in tel.staleness_hist]],
-            v=np.asarray(sim.engine.state.v).tolist()))
+            v=np.asarray(sim.engine.state.v).tolist(),
+            scopes=sorted({c for p in sim.engine.segment_scopes().values()
+                           for c in p.split("/")})))
     out[str(dp)] = runs
-print(json.dumps(out))
+# four devices again, no DP, with the scatter wrapper's psum (and only it)
+# made a no-op: each shard keeps its own partial ring sums
+import types
+lax = types.SimpleNamespace(**{k: getattr(jax.lax, k) for k in dir(jax.lax)
+                               if not k.startswith("__")})
+lax.psum = lambda x, axis_name: x
+tick_ops.jax = types.SimpleNamespace(**{k: getattr(jax, k) for k in dir(jax)
+                                        if not k.startswith("__")})
+tick_ops.jax.lax = lax
+jax.clear_caches()  # the wrappers' jit caches hold the traced psum
+task = LogRegTask(X, y, l2=1e-3, sample_seed=1)
+sim = DeviceCohortSimulator(task, n_clients=64, sizes_per_client=[1] * 4,
+                            round_stepsizes=[0.1] * 4, d=1, seed=0, block=1,
+                            scenario="uniform")
+sim.run(max_rounds=4, eval_every=2)
+print(json.dumps({"runs": out,
+                  "no_allreduce": np.asarray(sim.engine.state.v).tolist()}))
 """
 
 
-def test_sharded_client_axis_matches_one_device():
-    """Four virtual CPU devices: the engine shards C, the fused kernels
-    run per shard under shard_map and the ring sums are psum'ed.  The
-    integer census equals the one-device run's; the model differs only
-    by the add order of the shards' partial sums.  (A child process:
-    the device count is fixed when JAX starts.)"""
+@pytest.fixture(scope="module")
+def sharded_runs():
+    """The engine on four virtual CPU devices and on one, each with and
+    without DP, and on four without DP with the client-axis psum made a
+    no-op.  (A child process: the device count is fixed when JAX
+    starts.)"""
     import json
     import os
     import subprocess
@@ -324,8 +342,15 @@ def test_sharded_client_axis_matches_one_device():
     proc = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-4000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    for dp, (four, one) in out.items():
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_sharded_client_axis_matches_one_device(sharded_runs):
+    """Four virtual CPU devices: the engine shards C, the fused kernels
+    run per shard under shard_map and the ring sums are psum'ed.  The
+    integer census equals the one-device run's; the model differs only
+    by the add order of the shards' partial sums."""
+    for dp, (four, one) in sharded_runs["runs"].items():
         assert (four["devices"], one["devices"]) == (4, 1), dp
         assert four["census"] == one["census"], dp
         v4, v1 = np.asarray(four["v"]), np.asarray(one["v"])
@@ -333,3 +358,23 @@ def test_sharded_client_axis_matches_one_device():
         # shards: 4 * 2 * 64 * 2^-24 of the model's magnitude
         tol = 4 * 2 * 64 * 2.0 ** -24 * max(1.0, np.abs(v1).max())
         assert np.abs(v4 - v1).max() <= tol, (dp, np.abs(v4 - v1).max())
+
+
+def test_sharded_segment_scopes_its_allreduces(sharded_runs):
+    """The client-axis psums of a sharded fleet sit under
+    ``cohort.allreduce``, inside the scatter (and, with DP, the clip+
+    noise) kernel wrapper; one device has none."""
+    for dp, (four, one) in sharded_runs["runs"].items():
+        assert "cohort.allreduce" in four["scopes"], dp
+        assert "cohort.allreduce" not in one["scopes"], dp
+        assert ("cohort_clip_noise" in four["scopes"]) == (dp == "True")
+
+
+def test_sharded_fleet_needs_its_allreduce(sharded_runs):
+    """Without the scatter wrapper's client-axis psum each shard applies
+    only its own clients' ring sums: the model leaves the one-device run
+    by far more than the re-association bound above."""
+    one = np.asarray(sharded_runs["runs"]["False"][1]["v"])
+    broken = np.asarray(sharded_runs["no_allreduce"])
+    tol = 4 * 2 * 64 * 2.0 ** -24 * max(1.0, np.abs(one).max())
+    assert np.abs(broken - one).max() > 100 * tol
