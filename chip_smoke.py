@@ -2,13 +2,17 @@
 """Smoke run of the device engine on a TPU: the quickest proof that the
 repo's main path compiles and runs on the chip.
 
-    python chip_smoke.py             # one chip: phases logreg, logreg_dp, model
+    python chip_smoke.py             # one chip: phases logreg, logreg_dp,
+                                     # client_block, model
     python chip_smoke.py --chips 4   # phase logreg with the client axis
                                      # sharded over four chips, against
                                      # the same run on one chip
 
-Every phase goes through ``make_simulator(FLConfig(engine="device"), ...)``
-and checks what comes out.  Earlier lines report each phase; the last
+Every engine phase goes through
+``make_simulator(FLConfig(engine="device"), ...)`` and checks what comes
+out.  On a TPU the host engine's block is the same client-block kernel,
+so phase client_block holds the compiled kernel to its jnp reference
+directly, on masked multi-step blocks.  Earlier lines report each phase; the last
 line of stdout is ``{"ok": true, "device": {...}}``, printed only when
 every phase passed.  The script exits non-zero without that line when
 JAX finds no TPU, when the repo's ``src/`` is not beside it, or when a
@@ -35,6 +39,7 @@ N_EXAMPLES = 4096
 ROUNDS = 8
 SERVER_STEP = 1.0        # eta * C: the FedSGD server step per round
 TICK_KERNELS = ("tick_deliver", "bucket_apply", "tick_scatter")
+LOGREG_KERNELS = TICK_KERNELS + ("client_block_sgd",)
 
 
 def log(msg: str) -> None:
@@ -165,7 +170,7 @@ def phase_logreg(problems):
     spec = Logreg(dp=False)
     loss0 = spec.task.metrics(spec.task.init_model())["loss"]
     sim, res = run_device(spec, 1)
-    v = check_engine_run(sim, res, want_kernels=TICK_KERNELS,
+    v = check_engine_run(sim, res, want_kernels=LOGREG_KERNELS,
                          problems=problems)
     losses = [h["loss"] for h in res["history"]]
     log(f"  C={spec.C} D={sim.engine.D}: eval loss {loss0!r} -> "
@@ -191,7 +196,7 @@ def phase_logreg_dp(problems):
     spec = Logreg(dp=True)
     sim, res = run_device(spec, 1)
     v = check_engine_run(sim, res,
-                         want_kernels=TICK_KERNELS + ("cohort_clip_noise",),
+                         want_kernels=LOGREG_KERNELS + ("cohort_clip_noise",),
                          problems=problems)
     log(f"  operand noise: eval loss {res['history'][0]['loss']!r} -> "
         f"{res['final']['loss']!r} (sigma 8 noise on one-step rounds; "
@@ -205,7 +210,8 @@ def phase_logreg_dp(problems):
     # the draws go through the chi-square test of tests/test_tick_fused
     sim_k, res_k = run_device(spec, 1, dp_rng="in_kernel")
     check_engine_run(sim_k, res_k,
-                     want_kernels=TICK_KERNELS + ("cohort_clip_noise_prng",),
+                     want_kernels=LOGREG_KERNELS + (
+                         "cohort_clip_noise_prng",),
                      problems=problems)
     cen_k, cen = census(res_k["telemetry"]), census(res["telemetry"])
     if cen_k != cen:
@@ -219,6 +225,77 @@ def phase_logreg_dp(problems):
         test_in_kernel_prng_noise_chi_square(C, D)
         log(f"  in_kernel: chi-square of the in-kernel normals passed "
             f"(C={C}, D={D})")
+
+
+def _block_case(C, block, seed):
+    """A masked block of the logreg at D = 785: the data set, client
+    state with ``-0.0`` lanes, sample rows, step counts from 0 to the
+    whole block, step sizes."""
+    import jax
+    import jax.numpy as jnp
+    from repro.data import make_binary_dataset
+    X, y = make_binary_dataset(N_EXAMPLES, N_FEATURES, seed=seed)
+    X, y = jnp.asarray(X, jnp.float32), jnp.asarray(y, jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    D = N_FEATURES + 1
+    w = (0.1 * jax.random.normal(ks[0], (C, D))).at[::7, ::5].set(-0.0)
+    U = (0.1 * jax.random.normal(ks[1], (C, D))).at[::3, ::4].set(-0.0)
+    idx = jax.random.randint(ks[2], (C, block), 0, N_EXAMPLES)
+    n = jax.random.randint(ks[3], (C,), 0, block + 1)
+    n = n.at[::9].set(0).at[1::9].set(block)
+    eta = jax.random.uniform(ks[4], (C,), jnp.float32, 0.05, 0.5)
+    return X, y, w, U, idx, n, eta
+
+
+def check_client_block(C, block, problems, *, mesh=None):
+    """The compiled client-block kernel against its jnp reference
+    (``kernels/client_block/ref.py``) on the chip, on a masked block of
+    ``block`` steps for C clients, clip off and on (Fig. 1b's 0.1); under
+    ``mesh``, per client shard.  The row gather and the masks must be
+    exact: a client that takes no step keeps its state's values.  The
+    logit dot and the clip norm add over D in the kernel's order; each
+    step's gradient is held to the reduction-order bound 2 * D * 2^-24
+    of its size, compounded over the block (the bound of
+    tests/test_client_block.py)."""
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.kernels.client_block import client_block_ref, client_block_sgd
+    from repro.kernels.client_block.ops import steps_per_gather
+    X, y, w, U, idx, n, eta = _block_case(C, block, SEED + C)
+    D = w.shape[1]
+    shards = 1 if mesh is None else mesh.devices.size
+    X_aug = jnp.concatenate([X, y[:, None]], axis=1)
+    idle = np.asarray(n) == 0
+    for clip in (0.0, 0.1):
+        kw = dict(l2=1.0 / N_EXAMPLES, clip=clip)
+        ker = client_block_sgd(w, U, idx.T, n, eta, X_aug, mesh=mesh, **kw)
+        ref = client_block_ref(w, U, idx, n, eta, X, y, **kw)
+        errs = []
+        for name, k, r, old in zip("wU", ker, ref, (w, U)):
+            k, r, old = np.asarray(k), np.asarray(r), np.asarray(old)
+            tol = float(block * 2 * D * 2.0 ** -24 * np.abs(r - old).max()
+                        + block * 2.0 ** -24 * max(1.0, np.abs(r).max()))
+            err = float(np.abs(k - r).max())
+            errs.append(f"{name} {err!r} (bound {tol!r})")
+            if not err <= tol:
+                problems.append(f"client block C={C} clip={clip}: {name} "
+                                f"differs by {err} > {tol}")
+            if not (np.array_equal(k[idle], r[idle])
+                    and np.array_equal(k[idle], old[idle])):
+                problems.append(f"client block C={C} clip={clip}: an idle "
+                                f"client's {name} changed")
+        log(f"  C={C} over {shards} chip(s), block={block} (gathers of "
+            f"{steps_per_gather(block, C // shards, D)} steps) clip={clip}:"
+            f" kernel vs reference max |diff| {', '.join(errs)}; "
+            f"{int(idle.sum())} idle clients equal")
+
+
+def phase_client_block(problems):
+    """Masked 64-step blocks at C_LOGREG clients (one gather) and at
+    2^14 (the rows gathered in four chunks of 16 steps)."""
+    with on_chips(1):
+        for C in (C_LOGREG, 1 << 14):
+            check_client_block(C, 64, problems)
 
 
 def phase_model(problems):
@@ -238,7 +315,7 @@ def phase_logreg_4chips(problems):
     from repro.telemetry.costs import collectives_in
     spec = Logreg(dp=False)
     sim4, res4 = run_device(spec, 4)
-    v4 = check_engine_run(sim4, res4, want_kernels=TICK_KERNELS,
+    v4 = check_engine_run(sim4, res4, want_kernels=LOGREG_KERNELS,
                           problems=problems)
     colls = sorted(set(collectives_in(segment_text(sim4))))
     log(f"  client axis sharded over {sim4.engine.mesh.devices.size} "
@@ -251,6 +328,9 @@ def phase_logreg_4chips(problems):
     compare("4 chips vs 1 chip", v4, np.asarray(sim1.engine.state.v),
             census(res4["telemetry"]), census(res1["telemetry"]), spec.C,
             problems)
+    # the engine's blocks here take one step: the kernel per shard on
+    # masked 64-step blocks, against the reference
+    check_client_block(1 << 14, 64, problems, mesh=sim4.engine.mesh)
 
 
 def main(argv=None) -> int:
@@ -282,6 +362,7 @@ def main(argv=None) -> int:
         phases = [("logreg_4chips", phase_logreg_4chips)]
     else:
         phases = [("logreg", phase_logreg), ("logreg_dp", phase_logreg_dp),
+                  ("client_block", phase_client_block),
                   ("model", phase_model)]
     failed = []
     for name, fn in phases:
@@ -293,6 +374,10 @@ def main(argv=None) -> int:
         except Exception:  # noqa: BLE001 — reported, then the run fails
             problems.append(traceback.format_exc())
         secs = time.perf_counter() - t0
+        peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                   for d in devices[:args.chips])
+        log(f"  device memory peak so far: {peak} bytes (the most of any "
+            f"chip in use; peak_bytes_in_use)")
         if problems:
             failed.append(name)
             for p in problems:

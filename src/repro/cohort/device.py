@@ -121,7 +121,7 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
     buffered = strategy.buffered
     BUF = strategy.buffer_size if buffered else 0
     noise_base = jax.random.PRNGKey(seed ^ NOISE_SALT)   # == host engine's
-    run_block = ctask.block_body(b_stat)
+    run_block = ctask.block_body(b_stat, mesh=mesh)
     cidx = jnp.arange(C)
     S = STALE_BINS
     upd_bytes = jnp.int32(update_msg_bytes(D))

@@ -164,10 +164,11 @@ class CohortBatchModelTask:
             self._block_fns.pop(next(iter(self._block_fns)))
         return fn(w, U, i, h, n, eta)
 
-    def block_body(self, block: int):
+    def block_body(self, block: int, *, mesh=None):
         """The ``run_block`` computation, un-jitted (the device engine
         embeds it directly in its jitted tick; see
-        ``CohortLogRegTask.block_body``)."""
+        ``CohortLogRegTask.block_body``).  Plain XLA, which the compiler
+        partitions over a client ``mesh`` itself."""
         task = self.task
         cfg, remat, clip = task.cfg, task.remat, task.dp_clip
         batch_from_key = task.data_fn.batch_from_key

@@ -3,7 +3,8 @@ client axis.
 
 A cohort task exposes the same round computation as ``repro.core.tasks``
 but over flat ``[C, D]`` state blocks, advanced for the *whole population*
-in one jitted ``vmap``-of-``scan`` call (``run_block``).  Per-iteration
+in one jitted call (``run_block``): a ``vmap``-of-``scan``, or off-CPU
+the logreg task's fused client-block kernel.  Per-iteration
 sample draws are addressed by ``(client, round, iteration)`` via
 ``fold_in`` — the same derivation ``LogRegTask`` uses in its
 ``sample_seed`` mode — so a cohort trajectory is bit-reproducible against
@@ -11,13 +12,14 @@ the event simulator regardless of how either engine chunks a round.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.tasks import BatchModelTask, LogRegTask, clip_tree
-from repro.models import logreg
+from repro.core.tasks import BatchModelTask, LogRegTask
+from repro.kernels.client_block import client_block_ref, client_block_sgd
 
 
 class CohortLogRegTask:
@@ -41,6 +43,14 @@ class CohortLogRegTask:
         self.base_keys = jax.vmap(
             lambda c: jax.random.fold_in(base, c))(jnp.arange(self.C))
         self._block_fns: Dict[int, Any] = {}
+
+    @functools.cached_property
+    def X_aug(self):
+        """The data set with each row's label in its last column, where
+        the flat model has its bias: the client-block kernel's rows.
+        Built on the kernel path's first use (the reference reads ``X``
+        and ``y``), a second copy of the data set beside ``task.X``."""
+        return jnp.concatenate([self.task.X, self.task.y[:, None]], axis=1)
 
     # -- flat layout -------------------------------------------------------
     def flatten(self, m):
@@ -73,7 +83,7 @@ class CohortLogRegTask:
             self._block_fns.pop(next(iter(self._block_fns)))
         return fn(w, U, i, h, n, eta)
 
-    def block_body(self, block: int):
+    def block_body(self, block: int, *, use_kernel=None, mesh=None):
         """The ``run_block`` computation, un-jitted.
 
         The device-resident engine embeds this directly into its jitted
@@ -81,10 +91,17 @@ class CohortLogRegTask:
         would only add trace indirection; host callers go through
         ``run_block``, which jits and caches per block size.
 
+        ``use_kernel=None`` follows the backend, as the tick kernels do:
+        the fused client-block kernel (``repro.kernels.client_block``,
+        on ``X_aug``) off-CPU, the jnp reference (on ``X``, ``y``) on
+        CPU.  ``mesh``: the client mesh when C is sharded; the kernel
+        then runs per shard.
         """
+        if use_kernel is None:
+            use_kernel = jax.default_backend() != "cpu"
         X, y, l2 = self.task.X, self.task.y, self.task.l2
         clip, n_data = self.task.dp_clip, self.task.X.shape[0]
-        d = self.d_feat
+        X_aug = self.X_aug if use_kernel else None
         base_keys = self.base_keys
 
         def sample_idx(i, h):
@@ -102,31 +119,13 @@ class CohortLogRegTask:
 
             return jax.vmap(one)(round_keys, h)
 
-        def per_client(w_c, U_c, idx_c, n_c, eta_c):
-            params = {"w": w_c[:d], "b": w_c[d]}
-            upd = {"w": U_c[:d], "b": U_c[d]}
-
-            def body(carry, inp):
-                p, u = carry
-                idx, j = inp
-                g = jax.grad(logreg.per_example_loss)(p, X[idx], y[idx], l2)
-                if clip > 0.0:
-                    g = clip_tree(g, clip)
-                act = (j < n_c).astype(jnp.float32)
-                g = jax.tree_util.tree_map(lambda l: act * l, g)
-                u = jax.tree_util.tree_map(jnp.add, u, g)
-                p = jax.tree_util.tree_map(lambda a, gg: a - eta_c * gg,
-                                           p, g)
-                return (p, u), None
-
-            (params, upd), _ = jax.lax.scan(body, (params, upd),
-                                            (idx_c, jnp.arange(block)))
-            w_out = jnp.concatenate([params["w"], params["b"][None]])
-            u_out = jnp.concatenate([upd["w"], upd["b"][None]])
-            return w_out, u_out
-
         def run(w, U, i, h, n, eta):
-            return jax.vmap(per_client)(w, U, sample_idx(i, h), n, eta)
+            idx = sample_idx(i, h)
+            if use_kernel:
+                return client_block_sgd(w, U, idx.T, n, eta, X_aug, l2=l2,
+                                        clip=clip, mesh=mesh)
+            return client_block_ref(w, U, idx, n, eta, X, y, l2=l2,
+                                    clip=clip)
 
         return run
 

@@ -20,11 +20,15 @@ def predict_logits(params, x):
     return x @ params["w"] + params["b"]
 
 
+def bce_with_logits(z, y):
+    """Numerically stable BCE of labels ``y`` in {0,1} at logits ``z``."""
+    return jnp.maximum(z, 0.0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
+
+
 def per_example_loss(params, x, y, l2: float = 0.0):
     """x: (d,), y: scalar in {0,1}."""
     z = x @ params["w"] + params["b"]
-    # numerically stable BCE-with-logits
-    loss = jnp.maximum(z, 0.0) - z * y + jnp.log1p(jnp.exp(-jnp.abs(z)))
+    loss = bce_with_logits(z, y)
     if l2 > 0.0:
         loss = loss + 0.5 * l2 * jnp.sum(jnp.square(params["w"]))
     return loss
@@ -32,8 +36,7 @@ def per_example_loss(params, x, y, l2: float = 0.0):
 
 def batch_loss(params, xb, yb, l2: float = 0.0):
     z = xb @ params["w"] + params["b"]
-    losses = jnp.maximum(z, 0.0) - z * yb + jnp.log1p(jnp.exp(-jnp.abs(z)))
-    loss = jnp.mean(losses)
+    loss = jnp.mean(bce_with_logits(z, yb))
     if l2 > 0.0:
         loss = loss + 0.5 * l2 * jnp.sum(jnp.square(params["w"]))
     return loss

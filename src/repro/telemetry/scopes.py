@@ -21,9 +21,10 @@ SEGMENT_SCOPES = (
     "cohort.scenario", "cohort.complete", "cohort.predict_block",
 )
 #: the fused-kernel wrappers' scopes (``repro.kernels``): the kernel
-#: call with the wrapper's pads, slices and copies around it
+#: call with the wrapper's pads, slices and copies around it (and, for
+#: the client block, its sample-row gather)
 KERNEL_SCOPES = ("tick_deliver", "bucket_apply", "tick_scatter",
-                 "cohort_clip_noise")
+                 "cohort_clip_noise", "client_block_sgd")
 #: the client-axis all-reduces of a sharded fleet
 ALLREDUCE_SCOPE = "cohort.allreduce"
 DEVICE_SCOPES = SEGMENT_SCOPES + KERNEL_SCOPES + (ALLREDUCE_SCOPE,)

@@ -273,10 +273,14 @@ def test_fuse_ticks_bitwise_and_iter_relations():
 _SHARDED_SCRIPT = r"""
 import json, sys
 import jax, numpy as np
+import functools
 import repro.kernels.tick_fused.ops as tick_ops
+from repro.cohort.tasks import CohortLogRegTask
 # the fused kernels (interpret mode) in place of the CPU reference, so
-# their shard_map path runs
+# their shard_map path runs: the tick kernels and the client block
 tick_ops._resolve = lambda use_kernel, interpret: (True, True)
+CohortLogRegTask.block_body = functools.partialmethod(
+    CohortLogRegTask.block_body, use_kernel=True)
 from repro.cohort import DeviceCohortSimulator
 from repro.core import LogRegTask
 from repro.data import make_binary_dataset
@@ -368,6 +372,8 @@ def test_sharded_segment_scopes_its_allreduces(sharded_runs):
         assert "cohort.allreduce" in four["scopes"], dp
         assert "cohort.allreduce" not in one["scopes"], dp
         assert ("cohort_clip_noise" in four["scopes"]) == (dp == "True")
+        # the client block ran its kernel path, per shard on four
+        assert "client_block_sgd" in four["scopes"], dp
 
 
 def test_sharded_fleet_needs_its_allreduce(sharded_runs):
