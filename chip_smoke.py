@@ -3,7 +3,7 @@
 repo's main path compiles and runs on the chip.
 
     python chip_smoke.py             # one chip: phases logreg, logreg_dp,
-                                     # client_block, model
+                                     # client_block, model, granite_lora
     python chip_smoke.py --chips 4   # phase logreg with the client axis
                                      # sharded over four chips, against
                                      # the same run on one chip
@@ -12,7 +12,10 @@ Every engine phase goes through
 ``make_simulator(FLConfig(engine="device"), ...)`` and checks what comes
 out.  On a TPU the host engine's block is the same client-block kernel,
 so phase client_block holds the compiled kernel to its jnp reference
-directly, on masked multi-step blocks.  Earlier lines report each phase; the last
+directly, on masked multi-step blocks.  Phase granite_lora holds the
+compiled LoRA block of granite-4.0-h-micro at published widths to the
+benchmark's plain float32 reference (``bench/references``).  Earlier
+lines report each phase; the last
 line of stdout is ``{"ok": true, "device": {...}}``, printed only when
 every phase passed.  The script exits non-zero without that line when
 JAX finds no TPU, when the repo's ``src/`` is not beside it, or when a
@@ -310,6 +313,61 @@ def phase_model(problems):
         problems.append("model eval loss did not decrease")
 
 
+#: granite_lora: the compiled block's gradients may differ from the f32
+#: reference's by this share of the reference's largest: the block takes
+#: bfloat16 matmul inputs (the configuration's precision), and a random
+#: ten-layer period's gradients move by a few percent under that rounding
+#: (2% at hidden size 256 on the CPU); a wrong mixer, scale or residual
+#: moves them by order one
+GRANITE_GRAD_TOL = 0.1
+GRANITE_SEED = 2 ** 31 + 15
+
+
+def phase_granite_lora(problems):
+    """The compiled LoRA block at published widths, 2 clients x 1 step
+    from the seed's adapters, against the reference's gradients (and the
+    reference one precision step down, for scale)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    sys.path.insert(0, os.path.join(ROOT, "bench"))
+    from yardstick.measure import sized
+    from yardstick.spec import Cell, model_of, reference_of
+
+    cell = Cell("granite_h_micro_lora_fedavg")
+    config, traffic = sized(cell, {"clients": 2})
+    model, ref = model_of(config), reference_of(config, traffic)
+    with on_chips(1):
+        inputs = model.inputs(GRANITE_SEED, config)
+        _, ctask = model.build(config, inputs, GRANITE_SEED)
+        v0 = jnp.asarray(model.initial_vector(inputs))
+        C = ctask.C
+        zi = jnp.zeros((C,), jnp.int32)
+        block = jax.jit(ctask.block_body(1))
+        t0 = time.perf_counter()
+        _, U = block(jnp.tile(v0[None], (C, 1)), jnp.zeros((C, ctask.D)),
+                     zi, zi, zi + 1, jnp.zeros((C,)), ctask.operands)
+        U = np.asarray(U)
+        log(f"  block compiled and run in {time.perf_counter() - t0:.1f} s "
+            f"(smoke timing); D={ctask.D}")
+        base = jax.random.PRNGKey(GRANITE_SEED)
+        for cl in range(C):
+            key = jax.random.fold_in(jax.random.fold_in(
+                jax.random.fold_in(base, cl), 0), 0)
+            g, g_low = (np.asarray(ref.grad(v0, inputs["base"], key,
+                                            st=ref.statics(config, low)))
+                        for low in (False, True))
+            scale = float(np.abs(g).max())
+            err = float(np.abs(U[cl] - g).max()) / scale
+            low_err = float(np.abs(g_low - g).max()) / scale
+            log(f"  client {cl}: max |U - ref| / max |ref| {err!r} "
+                f"(tolerance {GRANITE_GRAD_TOL}); the control's "
+                f"{low_err!r}")
+            if not err <= GRANITE_GRAD_TOL:
+                problems.append(f"granite_lora client {cl}: gradients "
+                                f"differ by {err} > {GRANITE_GRAD_TOL}")
+
+
 def phase_logreg_4chips(problems):
     import numpy as np
     from repro.telemetry.costs import collectives_in
@@ -363,7 +421,8 @@ def main(argv=None) -> int:
     else:
         phases = [("logreg", phase_logreg), ("logreg_dp", phase_logreg_dp),
                   ("client_block", phase_client_block),
-                  ("model", phase_model)]
+                  ("model", phase_model),
+                  ("granite_lora", phase_granite_lora)]
     failed = []
     for name, fn in phases:
         log(f"phase {name}:")

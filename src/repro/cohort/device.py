@@ -80,7 +80,7 @@ from repro.sharding import cohort_mesh, cohort_shardings
 from repro.telemetry import (STALE_BINS, SpanRecorder, build_report,
                              op_scopes, open_trace, update_msg_bytes)
 from repro.telemetry.costs import (N_OPS, OP_BLOCK_TICKS, OP_FAR_GROUPS,
-                                   OP_FAR_TICKS, OP_RING_SCATTERS)
+                                   OP_FAR_TICKS, OP_RING_SCATTERS, ops_dict)
 
 # Unroll bound for the overflow bucket's per-completion-tick far-group
 # loop: one iteration per distinct far arrival tick.  Most tables have a
@@ -100,13 +100,21 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                    fuse_ticks: bool, seed: int, strategy, mesh=None):
     """Compile the eval-boundary segment runner for one configuration.
 
-    Returns ``segment(state, etas, sizes, accrual, target_k, tick_limit)``
-    — a jitted, state-donating function that advances the protocol until
-    ``server_k >= target_k`` or the tick budget runs out.  Per-instance
-    arrays (etas, sizes, accrual) are arguments rather than closure
-    constants so fresh engine instances with the same geometry reuse the
-    compiled executable.  ``mesh`` is the client mesh when C is sharded
-    over several devices: the fused client kernels then run per shard.
+    Returns ``segment(state, etas, sizes, accrual, target_k, tick_limit,
+    *task_ops)`` — a jitted, state-donating function that advances the
+    protocol until ``server_k >= target_k`` or the tick budget runs out.
+    Per-instance arrays (etas, sizes, accrual) are arguments rather than
+    closure constants so fresh engine instances with the same geometry
+    reuse the compiled executable.  ``task_ops`` is the cohort task's
+    ``operands`` (a model task's frozen base and keys), passed through
+    to its block and never donated; a task without them (logreg) takes
+    none, and its segment is as before.  ``mesh`` is the client mesh
+    when C is sharded over several devices: the fused client kernels
+    then run per shard.
+
+    A task with ``tokens_per_step`` gets one more op-census slot after
+    the ``N_OPS`` counters: the client steps its block took, masked
+    steps excluded (``client_tokens`` in the report).
     """
     dp_on = dp_sigma > 0.0 or dp_round_clip > 0.0
     noise_scale = dp_clip * dp_sigma
@@ -129,9 +137,10 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
     # latency-tick draws and the availability mask, pure jax ops the host
     # engine evaluates identically — the bit-parity contract
     avail_mask = plan.avail_mask
+    count_steps = getattr(ctask, "tokens_per_step", None) is not None
 
     def segment(st: DeviceCohortState, etas, sizes, accrual,
-                target_k, tick_limit) -> DeviceCohortState:
+                target_k, tick_limit, *task_ops) -> DeviceCohortState:
 
         def tick_fn(st: DeviceCohortState) -> DeviceCohortState:
             t = st.tick + 1
@@ -314,7 +323,7 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                     any_block,
                     lambda ops: run_block(*ops),
                     lambda ops: (ops[0], ops[1]),
-                    (w, st.U, st.i, st.h, n, eta))
+                    (w, st.U, st.i, st.h, n, eta) + task_ops)
                 h = st.h + n
 
             with jax.named_scope("cohort.complete"):
@@ -346,7 +355,7 @@ def _build_segment(ctask, *, C: int, D: int, block: int, b_stat: int,
                     any_done.astype(jnp.int32),              # complete_ticks
                     jnp.int32(0),           # far_ticks (do_complete)
                     jnp.int32(0),           # far_groups (do_far)
-                ])
+                ] + ([jnp.sum(n)] if count_steps else []))  # client steps
                 op_census = st.ops + op_inc
 
                 def do_complete(ops):
@@ -707,6 +716,7 @@ class DeviceCohortEngine:
             self.b_stat = next_pow2(
                 max(1, min(2 * self.block, int(self.sizes.max()))))
 
+            self._tokens_per_step = getattr(ctask, "tokens_per_step", None)
             self.mesh = cohort_mesh()
             self._shardings = cohort_shardings(self.mesh, C)
             # the fused client kernels need the mesh only when C is sharded
@@ -726,6 +736,11 @@ class DeviceCohortEngine:
                     jnp.asarray(speed_accrual(self.speeds, self.block),
                                 jnp.int32),
                     self._shardings["credit"])
+                # the task's operands (a model task's frozen base), held
+                # once and passed to every segment as they are
+                ops = getattr(ctask, "operands", None)
+                self._task_ops = (() if ops is None else
+                                  (jax.device_put(ops, self._replicated),))
             self.history: List[Dict[str, float]] = []
 
     def _init_state(self) -> DeviceCohortState:
@@ -764,7 +779,8 @@ class DeviceCohortEngine:
             buf_vec=jnp.zeros((D,) if self.strategy.buffered else (1,),
                               jnp.float32),
             buf_cnt=jnp.int32(0),
-            ops=jnp.zeros((N_OPS,), jnp.int32),
+            ops=jnp.zeros((N_OPS + (self._tokens_per_step is not None),),
+                          jnp.int32),
             iters=jnp.zeros((2,), jnp.int32))
         return DeviceCohortState(**{
             f: jax.device_put(val, self._shardings[f])
@@ -801,7 +817,8 @@ class DeviceCohortEngine:
                     fuse_ticks=self.fuse_ticks, seed=self.seed,
                     strategy=self.strategy, mesh=self._kernel_mesh,
                 ).lower(self.state, self._etas_dev, self._sizes_dev,
-                        self._accrual_dev, bound, bound).compile()
+                        self._accrual_dev, bound, bound,
+                        *self._task_ops).compile()
             self.compiles += 1
         return fn
 
@@ -868,7 +885,8 @@ class DeviceCohortEngine:
                         # unguarded: the first call may stage the
                         # executable's constants
                         st = seg(st, self._etas_dev, self._sizes_dev,
-                                 self._accrual_dev, tgt, lim)
+                                 self._accrual_dev, tgt, lim,
+                                 *self._task_ops)
                     else:
                         # runtime sanitizer (parity contract): a steady
                         # segment performs ZERO implicit host<->device
@@ -877,7 +895,8 @@ class DeviceCohortEngine:
                         # serializing the jitted tick loop
                         with jax.transfer_guard("disallow"):
                             st = seg(st, self._etas_dev, self._sizes_dev,
-                                     self._accrual_dev, tgt, lim)
+                                     self._accrual_dev, tgt, lim,
+                                     *self._task_ops)
                 self.state = st
                 with timer.phase("cohort.sync"):
                     sk = int(st.server_k)    # the one sync per segment
@@ -958,12 +977,18 @@ class DeviceCohortEngine:
                                           dtype=np.int64).sum()),
             staleness_hist=np.asarray(st.stale_hist),
             overflow_hwm=int(st.ovf_hwm),
-            ops=np.asarray(st.ops))
+            ops=np.asarray(st.ops)[:N_OPS])
 
     def telemetry_report(self, wall=None):
-        """MetricsReport from the on-device counters (syncs the state)."""
+        """MetricsReport from the on-device counters (syncs the state).
+        A task with ``tokens_per_step`` adds ``client_tokens`` to its
+        ``ops``: the client steps taken times the tokens of each."""
         st = self.state
         src_task = getattr(self.ctask, "task", None)
+        ops = np.asarray(st.ops, dtype=np.int64)
+        if self._tokens_per_step is not None:
+            ops = dict(ops_dict(ops[:N_OPS]),
+                       client_tokens=int(ops[N_OPS]) * self._tokens_per_step)
         return build_report(
             engine="device", clients=self.C, flat_dim=self.D,
             rounds=int(st.server_k), messages=int(st.messages),
@@ -975,7 +1000,7 @@ class DeviceCohortEngine:
             overflow_slots=self.Q if self.F else 0,
             far_messages=int(st.far_msgs),
             ticks=int(st.tick),
-            ops=np.asarray(st.ops, dtype=np.int64),
+            ops=ops,
             dp_sigma=self.dp_sigma, dp_delta=self.dp_delta,
             n_examples=(int(src_task.X.shape[0])
                         if hasattr(src_task, "X") else None),

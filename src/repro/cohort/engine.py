@@ -135,6 +135,10 @@ class CohortEngine:
                  interpret: Optional[bool] = None,
                  scenario=None, trace=None, dp_delta: float = 1e-5,
                  strategy=None):
+        if getattr(getattr(ctask, "task", None), "frozen", None) is not None:
+            raise ValueError(
+                "the host cohort engine does not run a task with a frozen "
+                "base (LoRA over a shared model): use engine='device'")
         self.ctask = ctask
         C = ctask.C
         self.C = C

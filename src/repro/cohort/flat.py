@@ -23,12 +23,23 @@ Two pieces:
   (``repro.data.federated``), regardless of how either engine chunks a
   round.
 
-Memory model: the engines hold the population as one ``[C, D]`` f32
-residency for models plus one for update accumulators (2 * C * D * 4
-bytes), sharded over local devices via ``repro.sharding.cohort_*`` —
-choose C and the model size so both blocks fit, and keep ``block`` small
-(a model-scale "iteration" is a full minibatch step, so a handful of
-iterations per round is the Bonawitz-style regime).
+Memory model: D is the width of what the clients train, the task's
+template.  For full training that is the whole model; for a task with a
+``frozen`` base (LoRA adapters, ``repro.models.lora``) it is the adapters
+alone, and the base is held once, in its own dtype, for every client.
+The engines hold the population as one ``[C, D]`` f32 residency for
+models plus one for update accumulators (2 * C * D * 4 bytes), sharded
+over local devices via ``repro.sharding.cohort_*``; the server keeps a
+few ``[D]`` rows besides (``v``, the arrival buckets, the broadcast
+ring), and the vmapped step its activations.  Choose C and D so these
+fit, and keep ``block`` small (a model-scale "iteration" is a full
+minibatch step, so a handful of iterations per round is the
+Bonawitz-style regime).
+
+The frozen base and the clients' base keys reach the compiled block as
+one operand (``operands``), which the device engine passes into its
+segment unchanged: nothing large is a constant of the executable, and
+the segment does not depend on the seed.
 """
 from __future__ import annotations
 
@@ -39,7 +50,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.tasks import BatchModelTask, clip_tree
-from repro.models import train_loss
 
 
 class PyTreeFlattener:
@@ -133,7 +143,16 @@ class CohortBatchModelTask:
         self.base_keys = jax.vmap(
             lambda c: jax.random.fold_in(batcher.base, c))(
                 jnp.arange(self.C))
+        # tokens of one client's minibatch: the device engine counts
+        # ``client_tokens`` by it
+        self.tokens_per_step = int(batcher.batch_size * batcher.seq_len)
         self._block_fns: Dict[int, Any] = {}
+
+    @property
+    def operands(self) -> Dict[str, Any]:
+        """What the compiled block reads besides the client state: the
+        frozen base (None without one) and the clients' base keys."""
+        return {"frozen": self.task.frozen, "keys": self.base_keys}
 
     # -- flat layout -------------------------------------------------------
     def flatten(self, tree):
@@ -162,20 +181,20 @@ class CohortBatchModelTask:
         self._block_fns[block] = fn
         while len(self._block_fns) > self.MAX_BLOCK_FNS:
             self._block_fns.pop(next(iter(self._block_fns)))
-        return fn(w, U, i, h, n, eta)
+        return fn(w, U, i, h, n, eta, self.operands)
 
     def block_body(self, block: int, *, mesh=None):
         """The ``run_block`` computation, un-jitted (the device engine
         embeds it directly in its jitted tick; see
-        ``CohortLogRegTask.block_body``).  Plain XLA, which the compiler
-        partitions over a client ``mesh`` itself."""
+        ``CohortLogRegTask.block_body``): ``run(w, U, i, h, n, eta,
+        operands)``, ``operands`` the task's ``operands``.  Plain XLA,
+        which the compiler partitions over a client ``mesh`` itself."""
         task = self.task
-        cfg, remat, clip = task.cfg, task.remat, task.dp_clip
+        clip = task.dp_clip
         batch_from_key = task.data_fn.batch_from_key
         flt = self.flattener
-        base_keys = self.base_keys
 
-        def per_client(w_c, U_c, rk_c, h_c, n_c, eta_c):
+        def per_client(w_c, U_c, rk_c, h_c, n_c, eta_c, frozen):
             params = flt.unflatten(w_c)
             upd = flt.unflatten(U_c, dtype=jnp.float32)
 
@@ -183,8 +202,7 @@ class CohortBatchModelTask:
                 p, u = carry
                 batch = batch_from_key(
                     jax.random.fold_in(rk_c, h_c + j))
-                g = jax.grad(
-                    lambda q: train_loss(cfg, q, batch, remat=remat))(p)
+                g = jax.grad(task.loss)(p, batch, frozen)
                 if clip > 0.0:
                     g = clip_tree(g, clip)
                 act = (j < n_c).astype(jnp.float32)
@@ -201,10 +219,11 @@ class CohortBatchModelTask:
                                             jnp.arange(block))
             return flt.flatten(params), flt.flatten(upd)
 
-        def run(w, U, i, h, n, eta):
+        def run(w, U, i, h, n, eta, operands):
             # one threefry per (client, round) hoisted out of the scan,
             # exactly CohortLogRegTask.sample_idx's derivation
-            round_keys = jax.vmap(jax.random.fold_in)(base_keys, i)
-            return jax.vmap(per_client)(w, U, round_keys, h, n, eta)
+            round_keys = jax.vmap(jax.random.fold_in)(operands["keys"], i)
+            return jax.vmap(per_client, (0, 0, 0, 0, 0, 0, None))(
+                w, U, round_keys, h, n, eta, operands["frozen"])
 
         return run

@@ -41,11 +41,16 @@ class ModelConfig:
     sliding_window: Optional[int] = None       # window size for local layers
     local_global_period: Optional[int] = None  # e.g. 2 => alternate local/global
     global_layers: Tuple[int, ...] = ()        # explicit global-attn layers (hymba)
+    position_embedding: str = "rope"           # rope | nope (no positions)
+    attention_multiplier: Optional[float] = None  # score scale; None: 1/sqrt(hd)
 
     # MLP
     d_ff: int = 0
     activation: str = "silu"                   # silu (swiglu) | geglu | gelu
     mlp_bias: bool = False
+    fused_gate_up: bool = False                # one [d, 2 d_ff] input projection
+    #                                            (gate | up), e.g. granite's
+    #                                            input_linear
 
     # Output
     logit_softcap: Optional[float] = None
@@ -56,6 +61,17 @@ class ModelConfig:
     norm_eps: float = 1e-6
     post_attn_norm: bool = False               # gemma2 style post-norms
     embed_scale: bool = False                  # gemma multiplies embeds by sqrt(d)
+
+    # Scale factors (granite): embeddings times embedding_multiplier, each
+    # residual branch times residual_multiplier, logits over logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+
+    # Per-layer mixer pattern: "mamba" or "attention" per layer, run in
+    # this order, each layer a mixer then the MLP (granite-4.0-h).  Empty:
+    # the family's one homogeneous layer
+    layer_types: Tuple[str, ...] = ()
 
     # MoE
     n_experts: int = 0
@@ -70,6 +86,8 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_conv_width: int = 4
     ssm_chunk: int = 128
+    ssm_n_groups: int = 1                      # B/C groups (the SSD takes one)
+    ssm_conv_bias: bool = True
 
     # Encoder-decoder (whisper)
     n_encoder_layers: int = 0
@@ -124,6 +142,9 @@ class ModelConfig:
         n += self.vocab_size * d            # embed
         if not self.tie_embeddings:
             n += self.vocab_size * d        # unembed
+        if self.layer_types:
+            return n + d + sum(self._pattern_layer_params(k)
+                               for k in self.layer_types)
         per_layer = 0
         if self.family != "ssm":
             q = self.n_heads * self.head_dim
@@ -152,6 +173,25 @@ class ModelConfig:
             enc_per = 4 * d * self.head_dim * self.n_heads + 2 * d * self.d_ff
             n += self.n_encoder_layers * enc_per
         return n
+
+
+    def _pattern_layer_params(self, kind: str) -> int:
+        """Parameters of one layer of a ``layer_types`` pattern: its
+        mixer, the gated MLP and the two norms."""
+        d = self.d_model
+        if kind == "attention":
+            q = self.n_heads * self.head_dim
+            kv = self.n_kv_heads * self.head_dim
+            mixer = d * q + 2 * d * kv + q * d
+        elif kind == "mamba":
+            di, H = self.ssm_d_inner, self.ssm_n_heads
+            conv = di + 2 * self.ssm_n_groups * self.ssm_state
+            mixer = (d * (di + conv + H) + conv * self.ssm_conv_width
+                     + (conv if self.ssm_conv_bias else 0) + 3 * H + di
+                     + di * d)
+        else:
+            raise ValueError(f"unknown layer type {kind!r}")
+        return mixer + 3 * d * self.d_ff + 2 * d
 
 
 def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,

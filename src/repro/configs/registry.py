@@ -24,10 +24,10 @@ def list_archs():
 
 
 def _populate() -> None:
-    from repro.configs import (chameleon_34b, gemma2_2b, gemma_2b, grok1_314b,
-                               hymba_15b, mamba2_780m, minitron_8b,
-                               paper_logreg, qwen15_32b, qwen2_moe_a27b,
-                               whisper_large_v3)
+    from repro.configs import (chameleon_34b, gemma2_2b, gemma_2b,
+                               granite_4_h_micro, grok1_314b, hymba_15b,
+                               mamba2_780m, minitron_8b, paper_logreg,
+                               qwen15_32b, qwen2_moe_a27b, whisper_large_v3)
     register("qwen1.5-32b", qwen15_32b.config)
     register("whisper-large-v3", whisper_large_v3.config)
     register("chameleon-34b", chameleon_34b.config)
@@ -39,6 +39,7 @@ def _populate() -> None:
     register("qwen2-moe-a2.7b", qwen2_moe_a27b.config)
     register("grok-1-314b", grok1_314b.config)
     register("paper-logreg", paper_logreg.config)
+    register("granite-4.0-h-micro", granite_4_h_micro.config)
 
 
 _populate()

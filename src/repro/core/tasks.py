@@ -8,7 +8,10 @@ segment length (the event simulator produces many lengths).
 
 ``BatchModelTask`` adapts any ``repro.models`` architecture: one "local
 iteration" = one minibatch-SGD step (the paper's footnote ‡ licenses batch
-SGD per round); DP clips the client's round update (user-level DP).
+SGD per round); DP clips the client's round update (user-level DP).  With
+a ``frozen`` base it trains only the template's parameters (LoRA
+adapters, ``repro.models.lora``) over that base, held once for every
+client.
 """
 from __future__ import annotations
 
@@ -172,22 +175,27 @@ class LogRegTask:
 
 
 class BatchModelTask:
-    """LLM-scale task: one local iteration = one minibatch-SGD step."""
+    """LLM-scale task: one local iteration = one minibatch-SGD step.
+
+    ``frozen``: a base model the clients share and do not train; the
+    template then holds the trained parameters alone (LoRA adapters over
+    that base), and only they are client state.  The base enters every
+    compiled step as an operand, never as a constant.
+    """
 
     def __init__(self, cfg, params_template, data_fn, *, dp_clip: float = 0.0,
-                 dp_sigma: float = 0.0, remat: bool = True):
-        from repro.models import train_loss
+                 dp_sigma: float = 0.0, remat: bool = True, frozen=None):
         self.cfg = cfg
         self.data_fn = data_fn           # (client_id, round, h, rng) -> batch
         self.dp_clip = float(dp_clip)
         self.dp_sigma = float(dp_sigma)
         validate_dp_knobs(self.dp_clip, self.dp_sigma, "BatchModelTask")
         self.template = params_template
+        self.frozen = frozen
         self.remat = bool(remat)
 
-        def step(w, U, batch, eta):
-            loss, g = jax.value_and_grad(
-                lambda p: train_loss(cfg, p, batch, remat=remat))(w)
+        def step(w, U, batch, eta, frozen):
+            loss, g = jax.value_and_grad(self.loss)(w, batch, frozen)
             if self.dp_clip > 0.0:
                 g = clip_tree(g, self.dp_clip)
             U = jax.tree_util.tree_map(jnp.add, U, g)
@@ -195,10 +203,18 @@ class BatchModelTask:
             return w, U, loss
 
         self._step = jax.jit(step)
-        self._eval_loss = jax.jit(
-            lambda p, batch: train_loss(cfg, p, batch, remat=remat))
+        self._eval_loss = jax.jit(self.loss)
         self._eval_batch = None
         self.last_loss = None
+
+    def loss(self, params, batch, frozen=None):
+        """The training loss of ``params`` (the adapters, over the
+        ``frozen`` base, when the task has one)."""
+        from repro.models import train_loss
+        if frozen is None:
+            return train_loss(self.cfg, params, batch, remat=self.remat)
+        return train_loss(self.cfg, frozen, batch, remat=self.remat,
+                          adapters=params)
 
     def init_model(self, key=None):
         """Default initial model: the params template (drivers that init
@@ -214,7 +230,8 @@ class BatchModelTask:
         for h in range(int(n_iters)):
             rng, sub = jax.random.split(rng)
             batch = self.data_fn(client_id, round_idx, start_h + h, sub)
-            w, U, loss = self._step(w, U, batch, jnp.float32(eta))
+            w, U, loss = self._step(w, U, batch, jnp.float32(eta),
+                                    self.frozen)
             self.last_loss = float(loss)
         return w, U
 
@@ -246,7 +263,8 @@ class BatchModelTask:
         if self._eval_batch is None:
             self._eval_batch = self.data_fn(0, 0, 0,
                                             jax.random.PRNGKey(0))
-        out = {"loss": float(self._eval_loss(w, self._eval_batch))}
+        out = {"loss": float(self._eval_loss(w, self._eval_batch,
+                                             self.frozen))}
         if self.last_loss is not None:
             out["last_train_loss"] = self.last_loss
         return out

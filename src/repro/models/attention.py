@@ -20,34 +20,38 @@ import jax.numpy as jnp
 import os
 
 from repro.models.common import (apply_rope, expand_rank, fan_in_init,
-                                 softcap, zeros_init)
+                                 matmul, softcap, zeros_init)
 
 NEG_INF = -2.0 ** 30
 
 
-def init_attention(cfg, key, dtype, *, cross: bool = False):
+def init_attention(cfg, key, dtype, *, cross: bool = False, n_layers=None):
+    L = n_layers if n_layers is not None else cfg.n_layers
     d, q_dim = cfg.d_model, cfg.n_heads * cfg.head_dim
     kv_dim = cfg.n_kv_heads * cfg.head_dim
     ks = jax.random.split(key, 4)
     p = {
-        "wq": fan_in_init(ks[0], (cfg.n_layers, d, q_dim), dtype),
-        "wk": fan_in_init(ks[1], (cfg.n_layers, d, kv_dim), dtype),
-        "wv": fan_in_init(ks[2], (cfg.n_layers, d, kv_dim), dtype),
-        "wo": fan_in_init(ks[3], (cfg.n_layers, q_dim, d), dtype),
+        "wq": fan_in_init(ks[0], (L, d, q_dim), dtype),
+        "wk": fan_in_init(ks[1], (L, d, kv_dim), dtype),
+        "wv": fan_in_init(ks[2], (L, d, kv_dim), dtype),
+        "wo": fan_in_init(ks[3], (L, q_dim, d), dtype),
     }
     if cfg.qkv_bias:
-        p["bq"] = jnp.zeros((cfg.n_layers, q_dim), dtype)
-        p["bk"] = jnp.zeros((cfg.n_layers, kv_dim), dtype)
-        p["bv"] = jnp.zeros((cfg.n_layers, kv_dim), dtype)
+        p["bq"] = jnp.zeros((L, q_dim), dtype)
+        p["bk"] = jnp.zeros((L, kv_dim), dtype)
+        p["bv"] = jnp.zeros((L, kv_dim), dtype)
     return p
 
 
-def _project_qkv(cfg, lp, x, positions, *, rope: bool = True):
-    """x: (B,S,d) -> q (B,S,H,hd), k/v (B,S,KV,hd)."""
+def _project_qkv(cfg, lp, x, positions, *, rope: bool = True,
+                 dense=matmul):
+    """x: (B,S,d) -> q (B,S,H,hd), k/v (B,S,KV,hd).  ``dense(name, x,
+    w)`` computes each projection.  No rotary embedding under
+    ``position_embedding == "nope"``."""
     B, S, _ = x.shape
-    q = jnp.einsum("bsd,dq->bsq", x, lp["wq"])
-    k = jnp.einsum("bsd,dk->bsk", x, lp["wk"])
-    v = jnp.einsum("bsd,dk->bsk", x, lp["wv"])
+    q = dense("wq", x, lp["wq"])
+    k = dense("wk", x, lp["wk"])
+    v = dense("wv", x, lp["wv"])
     if "bq" in lp:
         q = q + expand_rank(lp["bq"], q.ndim)
         k = k + expand_rank(lp["bk"], k.ndim)
@@ -55,7 +59,7 @@ def _project_qkv(cfg, lp, x, positions, *, rope: bool = True):
     q = q.reshape(B, S, cfg.n_heads, cfg.head_dim)
     k = k.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(B, S, cfg.n_kv_heads, cfg.head_dim)
-    if rope and cfg.rope_theta > 0:
+    if rope and cfg.rope_theta > 0 and cfg.position_embedding != "nope":
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     # (§Perf note: an attempted "project-then-gather-KV" constraint here
@@ -70,7 +74,8 @@ def _scores_to_out(cfg, q, k, v, mask):
     KV = k.shape[2]
     group = H // KV
     qg = q.reshape(B, Sq, KV, group, hd)
-    scale = 1.0 / math.sqrt(hd)
+    scale = (cfg.attention_multiplier if cfg.attention_multiplier is not None
+             else 1.0 / math.sqrt(hd))
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     scores = softcap(scores, cfg.attn_softcap)
@@ -92,7 +97,7 @@ def causal_mask(Sq: int, Sk: int, window) -> jnp.ndarray:
 
 
 def attend_full(cfg, lp, x, positions, window=None, *, rope=True,
-                q_chunk: int = None, unroll: bool = False):
+                q_chunk: int = None, unroll: bool = False, dense=matmul):
     if q_chunk is None:
         q_chunk = int(os.environ.get("REPRO_Q_CHUNK", "1024"))
     """Masked attention over the full sequence.
@@ -104,12 +109,11 @@ def attend_full(cfg, lp, x, positions, window=None, *, rope=True,
     variants only).
     """
     B, S, _ = x.shape
-    q, k, v = _project_qkv(cfg, lp, x, positions, rope=rope)
+    q, k, v = _project_qkv(cfg, lp, x, positions, rope=rope, dense=dense)
     if S <= q_chunk:
         mask = causal_mask(S, S, window)
         out = _scores_to_out(cfg, q, k, v, mask)
-        return jnp.einsum("bsq,qd->bsd",
-                          out.reshape(B, S, -1), lp["wo"])
+        return dense("wo", out.reshape(B, S, -1), lp["wo"])
 
     QC = q_chunk
     pad = (-S) % QC
@@ -141,7 +145,7 @@ def attend_full(cfg, lp, x, positions, window=None, *, rope=True,
                                (jnp.arange(nq, dtype=jnp.int32), qr))
         out = outs.transpose(1, 0, 2, 3, 4).reshape(B, S + pad, -1)
     out = out[:, :S] if pad else out.reshape(B, S, -1)
-    return jnp.einsum("bsq,qd->bsd", out.reshape(B, S, -1), lp["wo"])
+    return dense("wo", out.reshape(B, S, -1), lp["wo"])
 
 
 def attend_chunked(cfg, lp, x, positions, window: int, *, rope=True):

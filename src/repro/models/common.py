@@ -35,6 +35,13 @@ def ones_init(_key, shape, dtype):
     return jnp.ones(shape, dtype)
 
 
+def matmul(name, x, w):
+    """The default projection ``x @ w`` (x: (B,S,in), w: (in,out));
+    ``name`` is the weight's key, which a LoRA projection
+    (``repro.models.lora.projection``) uses to find its adapter."""
+    return jnp.einsum("bsd,dk->bsk", x, w)
+
+
 # ---------------------------------------------------------------------------
 # Norms
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import expand_rank, fan_in_init, gated_act
+from repro.models.common import expand_rank, fan_in_init, gated_act, matmul
 
 
 def init_mlp(cfg, key, dtype, *, n_layers=None, d_ff=None):
@@ -12,7 +12,12 @@ def init_mlp(cfg, key, dtype, *, n_layers=None, d_ff=None):
     ff = d_ff if d_ff is not None else cfg.d_ff
     d = cfg.d_model
     ks = jax.random.split(key, 3)
-    if cfg.activation in ("silu", "geglu"):
+    if cfg.fused_gate_up:
+        p = {
+            "wi": fan_in_init(ks[0], (L, d, 2 * ff), dtype),
+            "wd": fan_in_init(ks[2], (L, ff, d), dtype),
+        }
+    elif cfg.activation in ("silu", "geglu"):
         p = {
             "wg": fan_in_init(ks[0], (L, d, ff), dtype),
             "wu": fan_in_init(ks[1], (L, d, ff), dtype),
@@ -29,8 +34,14 @@ def init_mlp(cfg, key, dtype, *, n_layers=None, d_ff=None):
     return p
 
 
-def apply_mlp(cfg, lp, x):
-    """lp holds one layer's slices (no leading L axis)."""
+def apply_mlp(cfg, lp, x, *, dense=matmul):
+    """lp holds one layer's slices (no leading L axis).  ``dense(name,
+    x, w)`` computes each projection: ``wi`` (gate | up, fused), or
+    ``wg`` and ``wu``; then ``wd``."""
+    if "wi" in lp:
+        gate, up = jnp.split(dense("wi", x, lp["wi"]), 2, axis=-1)
+        h = gated_act(cfg.activation, gate, up)
+        return dense("wd", h, lp["wd"])
     if "wg" in lp:
         gate = jnp.einsum("bsd,df->bsf", x, lp["wg"])
         up = jnp.einsum("bsd,df->bsf", x, lp["wu"])

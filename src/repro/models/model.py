@@ -2,7 +2,8 @@
 
 All architectures expose:
     init_params(cfg, key, dtype)                -> params pytree
-    train_loss(cfg, params, batch, remat=True)  -> scalar loss (f32)
+    train_loss(cfg, params, batch, remat=True, adapters=None)
+                                                -> scalar loss (f32)
     init_cache(cfg, batch, cache_len, dtype)    -> decode cache pytree
     serve_step(cfg, params, cache, tokens, pos, seq_len) -> (logits, cache)
 """
@@ -24,10 +25,14 @@ def init_params(cfg, key, dtype=jnp.bfloat16):
 
 
 def train_loss(cfg, params, batch, *, remat: bool = True,
-               unroll: bool = False):
+               unroll: bool = False, adapters=None):
+    """``adapters``: LoRA adapters over the frozen ``params``
+    (``repro.models.lora``; a ``layer_types`` model)."""
     if cfg.family in _DECODER_FAMILIES:
         return transformer.train_loss(cfg, params, batch, remat=remat,
-                                      unroll=unroll)
+                                      unroll=unroll, adapters=adapters)
+    if adapters is not None:
+        raise ValueError(f"no LoRA adapters for family {cfg.family!r}")
     if cfg.family == "encdec":
         return encdec.train_loss(cfg, params, batch, remat=remat,
                                  unroll=unroll)

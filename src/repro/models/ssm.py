@@ -15,10 +15,16 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import expand_rank, fan_in_init, rms_norm
+from repro.models.common import expand_rank, fan_in_init, matmul, rms_norm
 
 
 def ssm_dims(cfg):
+    """(d_inner, heads H, state N, conv width of x|B|C, in_proj width):
+    in_proj gives z (d_inner), x (d_inner), B and C (N each) and dt (H).
+    The SSD here takes one B/C group (``ssm_n_groups`` = 1)."""
+    if cfg.ssm_n_groups != 1:
+        raise ValueError(f"ssm_n_groups={cfg.ssm_n_groups}: the SSD scan "
+                         "takes one B/C group")
     d_inner = cfg.ssm_expand * cfg.d_model
     H = d_inner // cfg.ssm_head_dim
     N = cfg.ssm_state
@@ -32,7 +38,7 @@ def init_ssm(cfg, key, dtype, n_layers=None):
     d = cfg.d_model
     d_inner, H, N, conv_dim, proj_dim = ssm_dims(cfg)
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "in_proj": fan_in_init(ks[0], (L, d, proj_dim), dtype),
         "conv_w": fan_in_init(ks[1], (L, conv_dim, cfg.ssm_conv_width), dtype),
         "conv_b": jnp.zeros((L, conv_dim), dtype),
@@ -42,6 +48,9 @@ def init_ssm(cfg, key, dtype, n_layers=None):
         "gate_norm": jnp.ones((L, d_inner), dtype),
         "out_proj": fan_in_init(ks[2], (L, d_inner, d), dtype),
     }
+    if not cfg.ssm_conv_bias:
+        del p["conv_b"]
+    return p
 
 
 # ---------------------------------------------------------------------------
@@ -83,8 +92,10 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None,
     # intra-chunk decay matrix L[i,j] = exp(dA_cum[i] - dA_cum[j]), j <= i
     seg = dA_cum[:, :, :, None, :] - dA_cum[:, :, None, :, :]   # (b,nc,Q,Q,h)
     tril = jnp.tril(jnp.ones((Q, Q), bool))
-    L = jnp.where(tril[None, None, :, :, None], jnp.exp(seg),
-                  0.0).astype(intra_dt)
+    # mask before the exp: above the diagonal seg > 0 can overflow, and
+    # an inf there makes the backward's 0 * inf a NaN
+    L = jnp.exp(jnp.where(tril[None, None, :, :, None], seg,
+                          -jnp.inf)).astype(intra_dt)
 
     CB = jnp.einsum("bcin,bcjn->bcij", Cc, Bc).astype(intra_dt)
     if os.environ.get("REPRO_SSD_TWOSTEP", "1") == "1":  # default ON (−32% mem)
@@ -151,8 +162,8 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None,
     return y, final
 
 
-def causal_depthwise_conv(x, w, b):
-    """x: (B,S,C), w: (C,W), b: (C,).  Causal depthwise conv."""
+def causal_depthwise_conv(x, w, b=None):
+    """x: (B,S,C), w: (C,W), b: (C,) or None.  Causal depthwise conv."""
     W = w.shape[-1]
     xp = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
     out = jax.lax.conv_general_dilated(
@@ -163,7 +174,9 @@ def causal_depthwise_conv(x, w, b):
         dimension_numbers=("NWC", "WIO", "NWC"),
         feature_group_count=x.shape[-1],
     )
-    return (out + expand_rank(b.astype(jnp.float32), out.ndim)).astype(x.dtype)
+    if b is not None:
+        out = out + expand_rank(b.astype(jnp.float32), out.ndim)
+    return out.astype(x.dtype)
 
 
 def _split_proj(cfg, zxbcdt):
@@ -175,13 +188,15 @@ def _split_proj(cfg, zxbcdt):
 
 
 def apply_ssm(cfg, lp, x, *, return_state: bool = False, ssd_fn=None,
-              unroll: bool = False):
-    """Full-sequence mamba2 mixer.  x: (B,S,d) -> (B,S,d)."""
+              unroll: bool = False, dense=matmul):
+    """Full-sequence mamba2 mixer.  x: (B,S,d) -> (B,S,d).  ``dense(name,
+    x, w)`` computes the ``in_proj`` and ``out_proj`` projections."""
     B_, S, _ = x.shape
-    zxbcdt = jnp.einsum("bsd,dk->bsk", x, lp["in_proj"])
+    zxbcdt = dense("in_proj", x, lp["in_proj"])
     z, xBC, dt, d_inner, H, N = _split_proj(cfg, zxbcdt)
 
-    xBC = jax.nn.silu(causal_depthwise_conv(xBC, lp["conv_w"], lp["conv_b"]))
+    xBC = jax.nn.silu(causal_depthwise_conv(xBC, lp["conv_w"],
+                                            lp.get("conv_b")))
     xs = xBC[..., :d_inner]
     Bm = xBC[..., d_inner:d_inner + N]
     Cm = xBC[..., d_inner + N:]
@@ -204,7 +219,7 @@ def apply_ssm(cfg, lp, x, *, return_state: bool = False, ssd_fn=None,
 
     y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
                  lp["gate_norm"], cfg.norm_eps)
-    out = jnp.einsum("bsk,kd->bsd", y, lp["out_proj"])
+    out = dense("out_proj", y, lp["out_proj"])
     if return_state:
         # conv state: last (W-1) xBC inputs (pre-activation path needs raw
         # conv input; we store the raw projection tail)

@@ -5,6 +5,14 @@ Per-layer params are stacked on a leading axis and the stack runs under one
 gemma2's local/global alternation is expressed as a scanned per-layer
 ``window`` scalar).  ``jax.checkpoint`` wraps the body when remat is on.
 
+A config with a per-layer mixer pattern (``cfg.layer_types``, granite-4.0-h)
+runs its layers in the published order instead: each maximal run of
+same-kind layers (``layer_runs``) is scanned over its own stacked
+params, and no layer holds the other kind's.  Such a model may take LoRA
+adapters (``repro.models.lora``) over a frozen base, and runs under the
+device scopes ``model.mamba``, ``model.attention``, ``model.mlp``,
+``model.lora`` and ``model.loss``.
+
 Loss materialization: logits for 256k vocabularies are never materialized
 for the full sequence — cross entropy runs in sequence chunks under
 ``jax.checkpoint`` (recompute in backward), bounding live memory.
@@ -12,6 +20,7 @@ for the full sequence — cross entropy runs in sequence chunks under
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
@@ -19,12 +28,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn
+from repro.models import lora as lora_mod
 from repro.models import mlp as mlp_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.common import (apply_norm, cross_entropy_loss, embed_tokens,
-                                 init_norm, normal_init, padded_vocab,
-                                 softcap, unembed)
+                                 expand_rank, init_norm, normal_init,
+                                 padded_vocab, softcap, unembed)
 from repro.sharding.context import constrain, shard_layer_param_cotangents
 
 
@@ -33,6 +43,8 @@ from repro.sharding.context import constrain, shard_layer_param_cotangents
 # ---------------------------------------------------------------------------
 
 def init_decoder(cfg, key, dtype):
+    if cfg.layer_types:
+        return init_pattern(cfg, key, dtype)
     ks = jax.random.split(key, 8)
     Vp = padded_vocab(cfg.vocab_size)
     params = {"embed": normal_init(ks[0], (Vp, cfg.d_model), dtype)}
@@ -85,8 +97,9 @@ def _layer_body(cfg, x, lp, window, positions, *, unroll=False,
     """One decoder layer.  x: (B,S,d).
 
     chunked_local_window: when set (static int), the layer uses the
-    block-local attention path (computes only window-adjacent chunks —
-    the beyond-paper FLOP saving; see EXPERIMENTS.md §Perf).
+    block-local attention path (``attn.attend_chunked``: computes only
+    the window-adjacent chunks, S·W instead of S²/2 score FLOPs; enabled
+    by ``REPRO_CHUNKED_LOCAL=1`` in ``forward``).
     """
     aux = jnp.float32(0.0)
     h = apply_norm(cfg, x, _idx(lp, "ln1"))
@@ -127,6 +140,9 @@ def forward(cfg, params, tokens, *, remat: bool = True,
     unroll=True runs a Python loop over layers (and inner chunk loops) so
     the compiled HLO has exact trip counts for cost analysis.
     """
+    if cfg.layer_types:
+        return forward_pattern(cfg, params, tokens, remat=remat,
+                               positions=positions, unroll=unroll)
     B, S = tokens.shape
     x = constrain(embed_tokens(cfg, params, tokens))
     if positions is None:
@@ -198,8 +214,10 @@ def forward(cfg, params, tokens, *, remat: bool = True,
 
 
 def chunked_loss(cfg, params, hidden, labels, mask=None, chunk: int = 512,
-                 unroll: bool = False):
-    """Cross entropy over sequence chunks (never materializes (B,S,V))."""
+                 unroll: bool = False, logits_fn=None):
+    """Cross entropy over sequence chunks (never materializes (B,S,V)).
+    ``logits_fn(cfg, params, h)``: the unembedding (``unembed``)."""
+    logits_fn = logits_fn or unembed
     B, S, _ = hidden.shape
     chunk = min(chunk, S)
     pad = (-S) % chunk
@@ -213,7 +231,7 @@ def chunked_loss(cfg, params, hidden, labels, mask=None, chunk: int = 512,
 
     @jax.checkpoint
     def one(h_c, y_c, m_c):
-        logits = unembed(cfg, params, h_c)
+        logits = logits_fn(cfg, params, h_c)
         logp = jax.nn.log_softmax(logits, axis=-1)
         ll = jnp.take_along_axis(logp, y_c[..., None], axis=-1)[..., 0]
         m = m_c.astype(jnp.float32)
@@ -241,8 +259,15 @@ def chunked_loss(cfg, params, hidden, labels, mask=None, chunk: int = 512,
 
 
 def train_loss(cfg, params, batch, *, remat: bool = True,
-               unroll: bool = False):
-    """Next-token LM loss.  batch: {"tokens": (B,S)} (+ optional mask)."""
+               unroll: bool = False, adapters=None):
+    """Next-token LM loss.  batch: {"tokens": (B,S)} (+ optional mask).
+    ``adapters``: a ``lora.LoRA`` over the frozen ``params`` (a
+    ``layer_types`` model only)."""
+    if cfg.layer_types:
+        return pattern_loss(cfg, params, batch, adapters, remat=remat,
+                            unroll=unroll)
+    if adapters is not None:
+        raise ValueError("LoRA adapters need a layer_types model")
     tokens = batch["tokens"]
     hidden, aux = forward(cfg, params, tokens[:, :-1], remat=remat,
                           unroll=unroll)
@@ -261,6 +286,8 @@ def train_loss(cfg, params, batch, *, remat: bool = True,
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg, batch: int, cache_len: int, dtype):
+    if cfg.layer_types:
+        raise NotImplementedError("decode of a layer_types model")
     cache = {}
     if cfg.family != "ssm":
         cache["kv"] = attn.init_kv_cache(cfg, batch, cache_len, dtype)
@@ -369,3 +396,153 @@ def serve_step(cfg, params, cache, tokens, pos, *, seq_len: int,
     x = apply_norm(cfg, x, params["final_norm"])
     logits = unembed(cfg, params, x)
     return logits, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Per-layer mixer pattern (cfg.layer_types: granite-4.0-h)
+# ---------------------------------------------------------------------------
+
+def layer_runs(cfg):
+    """[(kind, n)]: the maximal runs of same-kind layers of
+    ``cfg.layer_types``, in order (granite's first period: mamba x5,
+    attention x1, mamba x4)."""
+    runs = []
+    for kind in cfg.layer_types:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    return [(k, n) for k, n in runs]
+
+
+def init_pattern(cfg, key, dtype):
+    """Params of a ``layer_types`` model: ``embed``, ``runs`` (one dict
+    per run, each leaf stacked over the run's layers: ``ln1``, the mixer
+    ``ssm`` or ``attn``, ``ln2``, ``mlp``) and ``final_norm``."""
+    if len(cfg.layer_types) != cfg.n_layers:
+        raise ValueError(f"{len(cfg.layer_types)} layer_types for "
+                         f"{cfg.n_layers} layers")
+    runs_kn = layer_runs(cfg)
+    ks = jax.random.split(key, 3 + len(runs_kn))
+    d = cfg.d_model
+    Vp = padded_vocab(cfg.vocab_size)
+    params = {"embed": normal_init(ks[0], (Vp, d), dtype),
+              "final_norm": init_norm(cfg, ks[1], d, dtype)}
+    if not cfg.tie_embeddings:
+        params["unembed"] = normal_init(ks[2], (Vp, d), dtype)
+    runs = []
+    for (kind, n), k in zip(runs_kn, ks[3:]):
+        k = jax.random.split(k, 4)
+        run = {"ln1": _stack_norm(cfg, k[0], n, d, dtype),
+               "ln2": _stack_norm(cfg, k[1], n, d, dtype),
+               "mlp": mlp_mod.init_mlp(cfg, k[2], dtype, n_layers=n)}
+        if kind == "mamba":
+            run["ssm"] = ssm_mod.init_ssm(cfg, k[3], dtype, n_layers=n)
+        elif kind == "attention":
+            run["attn"] = attn.init_attention(cfg, k[3], dtype, n_layers=n)
+        else:
+            raise ValueError(f"unknown layer type {kind!r}")
+        runs.append(run)
+    params["runs"] = tuple(runs)
+    return params
+
+
+def _mamba_mixer(cfg, lp, h, ad, scale, positions, unroll):
+    with jax.named_scope("model.mamba"):
+        return ssm_mod.apply_ssm(cfg, lp["ssm"], h, unroll=unroll,
+                                 dense=lora_mod.projection(ad.get("ssm"),
+                                                           scale))
+
+
+def _attention_mixer(cfg, lp, h, ad, scale, positions, unroll):
+    with jax.named_scope("model.attention"):
+        return attn.attend_full(cfg, lp["attn"], h, positions, None,
+                                unroll=unroll,
+                                dense=lora_mod.projection(ad.get("attn"),
+                                                          scale))
+
+
+def _pattern_layer(cfg, mixer, x, lp, ad, scale, positions, unroll):
+    """One layer: x + r * mixer(norm(x)), then x + r * mlp(norm(x)), r
+    the residual multiplier.  ``ad``: the layer's adapters or None."""
+    ad = ad or {}
+    r = cfg.residual_multiplier
+    x = x + r * mixer(cfg, lp, apply_norm(cfg, x, lp["ln1"]), ad, scale,
+                      positions, unroll)
+    with jax.named_scope("model.mlp"):
+        h = apply_norm(cfg, x, lp["ln2"])
+        f = mlp_mod.apply_mlp(cfg, lp["mlp"], h,
+                              dense=lora_mod.projection(ad.get("mlp"),
+                                                        scale))
+    return x + r * f
+
+
+def forward_pattern(cfg, params, tokens, *, adapters=None,
+                    remat: bool = True, positions=None,
+                    unroll: bool = False):
+    """tokens (B,S) -> final hidden states (B,S,d) f32 and a zero aux
+    loss.  The residual stream is float32; each projection computes in
+    its weight's dtype with float32 accumulation (``lora.linear``)."""
+    B, S = tokens.shape
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
+                                     (B, S))
+    x = (jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
+         * cfg.embedding_multiplier)
+    scale = adapters.scale if adapters is not None else 1.0
+    ad_runs = (adapters.layers if adapters is not None
+               else (None,) * len(params["runs"]))
+    mixers = {"mamba": _mamba_mixer, "attention": _attention_mixer}
+    for (kind, n), lp_run, ad_run in zip(layer_runs(cfg), params["runs"],
+                                         ad_runs):
+        def body(x, per, mixer=mixers[kind]):
+            lp, ad = per
+            return _pattern_layer(cfg, mixer, x, lp, ad, scale, positions,
+                                  unroll), None
+
+        if remat:
+            body = jax.checkpoint(body)
+        if unroll:
+            for li in range(n):
+                x, _ = body(x, jax.tree_util.tree_map(
+                    lambda a: a[li], (lp_run, ad_run)))
+        else:
+            x, _ = jax.lax.scan(body, x, (lp_run, ad_run))
+    x = apply_norm(cfg, x, params["final_norm"])
+    return x, jnp.float32(0.0)
+
+
+def pattern_logits(cfg, params, h):
+    """Logits over ``logits_scaling``: h (..., d) against the (tied)
+    table, in the table's dtype with float32 accumulation."""
+    table = params["embed"] if cfg.tie_embeddings else params["unembed"]
+    logits = jnp.einsum("...d,vd->...v", h.astype(table.dtype), table,
+                        preferred_element_type=jnp.float32)
+    logits = logits / cfg.logits_scaling
+    if table.shape[0] != cfg.vocab_size:
+        valid = jnp.arange(table.shape[0]) < cfg.vocab_size
+        logits = jnp.where(expand_rank(valid, logits.ndim), logits, -1e30)
+    return logits
+
+
+def pattern_loss_chunk(cfg) -> int:
+    """Sequence chunk of the loss: about 2^23 logits a sequence at once
+    (64 positions at a 100k vocabulary), at most 512."""
+    v = padded_vocab(cfg.vocab_size)
+    return int(max(16, min(512, 2 ** int(math.log2(max(1, 2 ** 23 // v))))))
+
+
+def pattern_loss(cfg, params, batch, adapters=None, *, remat: bool = True,
+                 unroll: bool = False):
+    """Next-token loss of a ``layer_types`` model (base ``params``,
+    optional ``adapters``)."""
+    tokens = batch["tokens"]
+    hidden, _ = forward_pattern(cfg, params, tokens[:, :-1],
+                                adapters=adapters, remat=remat,
+                                unroll=unroll)
+    mask = batch.get("mask")
+    with jax.named_scope("model.loss"):
+        return chunked_loss(cfg, params, hidden, tokens[:, 1:],
+                            None if mask is None else mask[:, 1:],
+                            chunk=pattern_loss_chunk(cfg), unroll=unroll,
+                            logits_fn=pattern_logits)

@@ -27,20 +27,32 @@ KERNEL_SCOPES = ("tick_deliver", "bucket_apply", "tick_scatter",
                  "cohort_clip_noise", "client_block_sgd")
 #: the client-axis all-reduces of a sharded fleet
 ALLREDUCE_SCOPE = "cohort.allreduce"
-DEVICE_SCOPES = SEGMENT_SCOPES + KERNEL_SCOPES + (ALLREDUCE_SCOPE,)
+#: a ``layer_types`` model's parts inside the client block
+#: (``repro.models.transformer``; ``model.lora`` nests in the others)
+MODEL_SCOPES = ("model.mamba", "model.attention", "model.mlp",
+                "model.lora", "model.loss")
+DEVICE_SCOPES = (SEGMENT_SCOPES + KERNEL_SCOPES + (ALLREDUCE_SCOPE,)
+                 + MODEL_SCOPES)
 
 _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=\s.*?\bmetadata=\{[^}]*?"
     r'\bop_name="([^"]*)"', re.M)
 
 
+_WRAP_RE = re.compile(r"^(?:[A-Za-z_]+\()+(.*?)\)*$")
+
+
 def scope_path(op_name: str) -> str:
     """The device scopes in an ``op_name``, outermost first, joined by
     ``/`` (``"cohort.segment/cohort.complete/tick_scatter"``); ``""``
     when the op lies under none.  A scope repeated at once (a Pallas
-    kernel's own name under its wrapper's scope) is kept once."""
+    kernel's own name under its wrapper's scope) is kept once.  A scope
+    opened at the top of a differentiated function reads as
+    ``transpose(jvp(model.loss))``: the wrappers are taken off."""
     out = []
     for c in op_name.split("/"):
+        m = _WRAP_RE.match(c)
+        c = m.group(1) if m else c
         if c in DEVICE_SCOPES and (not out or out[-1] != c):
             out.append(c)
     return "/".join(out)
