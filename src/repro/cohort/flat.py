@@ -38,8 +38,10 @@ Bonawitz-style regime).
 
 The frozen base and the clients' base keys reach the compiled block as
 one operand (``operands``), which the device engine passes into its
-segment unchanged: nothing large is a constant of the executable, and
-the segment does not depend on the seed.
+segment unchanged: nothing large is a constant of the executable.
+Without DP the segment does not depend on the seed either: its lowered
+module is the same for every seed.  With DP on, the segment's noise key
+is drawn from the seed as a constant, and each seed compiles its own.
 """
 from __future__ import annotations
 
