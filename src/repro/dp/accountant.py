@@ -14,12 +14,17 @@ the constants explicit.  This module implements:
   * ``moments_epsilon``    — a *numerical* accountant from Lemma 4's explicit
                               moment bound: works for arbitrary {s_i}, used
                               to cross-check the closed forms.
+  * ``keys_epsilon``       — the same accountant over many size rows at
+                              once (the per-client telemetry rows), equal to
+                              ``moments_epsilon`` row by row.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
 from typing import List, Optional, Sequence
+
+import numpy as np
 
 E = math.e
 SQRT3M1_HALF = (math.sqrt(3.0) - 1.0) / 2.0
@@ -321,6 +326,147 @@ def moments_epsilon(sizes: Sequence[int], N_c: int, sigma: float,
 # Per-client accounting (telemetry)
 # ---------------------------------------------------------------------------
 
+def participation_keys(schedules, participation: Sequence[int]):
+    """The distinct (schedule, participation count) keys of a fleet.
+
+    ``schedules`` is a [C, L] array or C sequences of any lengths: row c
+    is client c's round schedule s_{i,c}, its last size repeating past
+    its end (``Client.s``); client c sent ``participation[c]`` updates.
+    Returns ``(table, counts, inverse)``: key k charges the first
+    ``counts[k]`` sizes of ``table[k]`` ([K, W] int64, W >= every
+    count), and client c holds key ``inverse[c]``.  A fleet on one
+    schedule has one key per distinct count.
+    """
+    part = np.asarray(participation, np.int64)
+    if isinstance(schedules, np.ndarray):
+        tab = schedules.astype(np.int64, copy=False)
+    else:
+        lens = [len(s) for s in schedules]
+        tab = np.zeros((len(lens), max([*lens, 1])), np.int64)
+        for c, (s, n) in enumerate(zip(schedules, lens)):
+            tab[c, :n] = s
+            tab[c, n:] = s[-1] if n else 0
+    if (tab == tab[:1]).all():       # one schedule: keys are the counts
+        rows, row_of = tab[:1], np.zeros(len(tab), np.int64)
+    else:
+        rows, row_of = np.unique(tab, axis=0, return_inverse=True)
+    span = int(part.max(initial=0)) + 1
+    keys, inverse = np.unique(row_of.reshape(-1) * span + part,
+                              return_inverse=True)
+    row_k, counts = np.divmod(keys, span)
+    L = rows.shape[1]
+    width = max(int(counts.max(initial=0)), L)
+    table = rows[row_k][:, np.minimum(np.arange(width), L - 1)]
+    return table, counts, inverse.reshape(-1)
+
+
+def keys_epsilon(table: np.ndarray, counts: np.ndarray, N_c: int,
+                 sigma: float, delta: float, *, r0: Optional[float] = None
+                 ) -> np.ndarray:
+    """``moments_epsilon`` of every key at once ([K] float64).
+
+    Key k is the first ``counts[k]`` sizes of ``table[k]``.  Equal, key
+    by key, to ``moments_epsilon`` of that row: the same float64
+    operations in the same order, on [keys, λ] arrays.  Only the sums
+    Σ_i α_i(λ) do not depend on ε, so they are taken once (left to
+    right over the rounds, each key with its own r0 default), not once
+    a bisection step.  As in the accounting rows: an empty key costs 0;
+    σ <= 0, or σ below Lemma 4's regime (u0/u1 >= 1), costs ∞.
+    """
+    tol, lambda_max = 1e-4, 256      # moments_epsilon's, moments_delta's
+    counts = np.asarray(counts, np.int64)
+    eps = np.where(counts > 0, math.inf, 0.0)
+    if sigma <= 0:
+        return eps
+    # r / r0 per key, r0 by default from the key's largest size
+    rr = np.full(len(counts), math.nan)
+    for k in np.flatnonzero(counts):
+        k0 = r0
+        if k0 is None:
+            k0 = int(table[k, :counts[k]].max()) / N_c * sigma
+            k0 = min(max(k0, 1e-6), 1.0 / E)
+        try:
+            rr[k] = r_from_r0(k0, sigma) / k0
+        except ValueError:
+            pass                     # no finite moments bound: ∞
+    live = np.flatnonzero(~np.isnan(rr))
+    if not live.size:
+        return eps
+    # the keys by count, longest first: round i sums into the first m
+    live = live[np.argsort(-counts[live], kind="stable")]
+    n, rr = counts[live], rr[live]
+    used = table[live, :n[0]]
+    charged = np.arange(n[0]) < n[:, None]
+    uniq = np.unique(used[charged])
+    u_of = np.minimum(np.searchsorted(uniq, used), len(uniq) - 1)
+    # moments_delta's per-size factors, as it computes them
+    sc = [min(int(u), N_c - 1) for u in uniq]
+    den1 = np.array([N_c * (N_c - s) * sigma ** 2 for s in sc])
+    den2 = np.array([N_c * (N_c - s) ** 2 * sigma ** 3 for s in sc])
+    s3 = np.array([float(s ** 3) for s in sc])
+    cap = np.array([sigma ** 2 * math.log(max(N_c / (s * sigma), E))
+                    for s in sc])
+    lam = np.arange(1, lambda_max + 1)
+    lamf, lam2, lam1 = (x.astype(np.float64)
+                        for x in (lam, lam ** 2, lam + 1))
+    # s²λ(λ+1) as a Python int, then one rounding to float64
+    t1_num = np.multiply.outer(np.array([s * s for s in sc], object),
+                               (lam * (lam + 1)).astype(object)
+                               ).astype(np.float64)
+    total = np.zeros((len(live), lambda_max))
+    for i in range(int(n[0])):
+        m = int(np.count_nonzero(n > i))
+        u = u_of[:m, i]
+        t1 = t1_num[u] / den1[u, None]
+        t2 = rr[:m, None] * s3[u, None] * lam2 * lam1 / den2[u, None]
+        total[:m] += t1 + t2
+    # λ is valid up to the first size whose cap it passes
+    capmin = np.where(charged, cap[u_of], math.inf).min(axis=1)
+    valid = lam <= capmin[:, None]
+
+    def delta_at(keys, e):
+        best = np.where(valid[keys], total[keys] - lamf * e[:, None],
+                        math.inf).min(axis=1)
+        return np.array([math.exp(b) if b < math.inf else 1.0
+                         for b in best.tolist()])
+
+    lo = np.full(len(live), 1e-4)
+    hi = np.full(len(live), 200.0)
+    bounded = ~(delta_at(np.arange(len(live)), hi) > delta)
+    act = np.flatnonzero(bounded)
+    for _ in range(80):
+        if not act.size:
+            break
+        mid = 0.5 * (lo[act] + hi[act])
+        ok = delta_at(act, mid) <= delta
+        hi[act] = np.where(ok, mid, hi[act])
+        lo[act] = np.where(ok, lo[act], mid)
+        act = act[~(hi[act] - lo[act] < tol)]
+    eps[live] = np.where(bounded, hi, math.inf)
+    return eps
+
+
+def keys_accounting(table: np.ndarray, counts: np.ndarray,
+                    inverse: np.ndarray, N_c: int, sigma: float,
+                    delta: float, *, r0: Optional[float] = None
+                    ) -> List[dict]:
+    """Per-client (ε, σ, rounds-contributed) rows for a MetricsReport,
+    from a fleet's ``participation_keys``: one ``keys_epsilon`` for
+    every key, then one row per client.  An infinite ε (σ too small for
+    δ at this N_c) is reported as ``None`` so the rows stay
+    JSON-serializable."""
+    eps = keys_epsilon(table, counts, N_c, sigma, delta, r0=r0)
+    charged = np.arange(table.shape[1]) < np.asarray(counts)[:, None]
+    samples = np.where(charged, table, 0).sum(axis=1)
+    per_key = [(int(n), int(s), None if math.isinf(e) else float(e))
+               for n, s, e in zip(counts, samples, eps.tolist())]
+    sigma, delta = float(sigma), float(delta)
+    return [{"client": c, "rounds_contributed": n, "samples": s,
+             "sigma": sigma, "delta": delta, "epsilon": e}
+            for c, (n, s, e) in enumerate(per_key[k]
+                                          for k in inverse.tolist())]
+
+
 def per_client_accounting(sizes_rows: Sequence[Sequence[int]], N_c: int,
                           sigma: float, delta: float, *,
                           r0: Optional[float] = None
@@ -331,34 +477,10 @@ def per_client_accounting(sizes_rows: Sequence[Sequence[int]], N_c: int,
     sent* (its participation record, not the planned schedule) — in the
     paper's local-DP regime each client's privacy spend depends only on
     its own mechanism invocations, so the moments accountant runs per
-    client over that row.  Identical rows share one bisection via a
-    cache, so fleets with a common schedule cost a single accountant
-    pass.  An infinite ε (σ too small for δ at this N_c) is reported as
-    ``None`` so the rows stay JSON-serializable.
+    client over that row.  Identical rows are one key of
+    ``keys_accounting``, so fleets with a common schedule cost one
+    accountant pass per distinct row.
     """
-    cache: dict = {}
-    rows: List[dict] = []
-    for c, sizes in enumerate(sizes_rows):
-        key = tuple(int(s) for s in sizes)
-        if key not in cache:
-            if not key or sigma <= 0:
-                eps = 0.0 if not key else math.inf
-            else:
-                try:
-                    eps = moments_epsilon(list(key), N_c, sigma, delta,
-                                          r0=r0)
-                except ValueError:
-                    # sigma below Lemma 4's validity regime (u0/u1 >= 1):
-                    # no finite moments bound — report as unbounded
-                    eps = math.inf
-            cache[key] = eps
-        eps = cache[key]
-        rows.append({
-            "client": c,
-            "rounds_contributed": len(key),
-            "samples": int(sum(key)),
-            "sigma": float(sigma),
-            "delta": float(delta),
-            "epsilon": None if math.isinf(eps) else float(eps),
-        })
-    return rows
+    return keys_accounting(
+        *participation_keys(sizes_rows, [len(r) for r in sizes_rows]),
+        N_c, sigma, delta, r0=r0)

@@ -169,13 +169,13 @@ def participation_sizes(sizes_per_client: Sequence[Sequence[int]],
     ``sizes_per_client[c]`` is the round-schedule s_{i,c} (the last entry
     repeats past the end, matching ``Client.s``); the returned row for
     client c has exactly ``participation[c]`` entries — the sample sizes
-    the moments accountant must charge for that client.
+    the moments accountant charges for that client (the list form of
+    ``participation_keys``, which ``build_report`` accounts by).
     """
-    rows: List[List[int]] = []
-    for c, done in enumerate(participation):
-        sched = list(sizes_per_client[c])
-        rows.append([sched[min(i, len(sched) - 1)] for i in range(int(done))])
-    return rows
+    from repro.dp.accountant import participation_keys
+    table, counts, inverse = participation_keys(sizes_per_client,
+                                                participation)
+    return [table[k, :counts[k]].tolist() for k in inverse]
 
 
 def build_report(*, engine: str, clients: int, flat_dim: int, rounds: int,
@@ -196,8 +196,10 @@ def build_report(*, engine: str, clients: int, flat_dim: int, rounds: int,
     Derives bytes_down (every fired broadcast reaches the whole fleet)
     and, when ``dp_sigma > 0`` and the dataset size is known, the
     per-client DP accounting rows from the rounds each client actually
-    contributed; ``spans`` (a ``SpanRecorder``) times that accounting
-    as ``cohort.dp_accounting``.
+    contributed: one accountant pass over the fleet's distinct
+    (schedule, count) keys.  ``spans`` (a ``SpanRecorder``) times that
+    pass as ``cohort.dp_accounting``, with args ``keys`` (K) and
+    ``clients`` (C).
     """
     ub = update_msg_bytes(flat_dim)
     bb = broadcast_msg_bytes(flat_dim)
@@ -207,12 +209,12 @@ def build_report(*, engine: str, clients: int, flat_dim: int, rounds: int,
     dp_rows = None
     if (dp_sigma > 0 and n_examples is not None
             and sizes_per_client is not None and len(sizes_per_client)):
-        from repro.dp.accountant import per_client_accounting
-        with (spans.phase("cohort.dp_accounting") if spans is not None
+        from repro.dp.accountant import keys_accounting, participation_keys
+        keys = participation_keys(sizes_per_client, part)
+        with (spans.phase("cohort.dp_accounting", keys=len(keys[1]),
+                          clients=len(part)) if spans is not None
               else contextlib.nullcontext()):
-            rows = participation_sizes(sizes_per_client, part)
-            dp_rows = per_client_accounting(rows, n_examples, dp_sigma,
-                                            dp_delta)
+            dp_rows = keys_accounting(*keys, n_examples, dp_sigma, dp_delta)
     return MetricsReport(
         engine=engine, clients=int(clients), flat_dim=int(flat_dim),
         rounds=int(rounds), messages=int(messages),

@@ -21,6 +21,7 @@ from repro.cohort import CohortSimulator, DeviceCohortSimulator
 from repro.core import AsyncFLSimulator, LogRegTask
 from repro.data import make_binary_dataset
 from repro.dp import moments_epsilon, per_client_accounting
+from repro.dp.accountant import keys_accounting, participation_keys
 from repro.scenarios import LatencyTable, Scenario
 from repro.telemetry import (HEADER_BYTES, OP_NAMES, STALE_BINS,
                              JsonlTraceWriter, MetricsReport,
@@ -191,6 +192,91 @@ def test_participation_sizes_prefix_rule():
     assert rows[1] == [5, 5]
 
 
+def test_participation_sizes_from_engine_table():
+    # the cohort engines hold their schedules as a dense [C, L] table
+    table = np.array([[4, 6, 8], [4, 6, 8], [5, 5, 5]], np.int64)
+    rows = participation_sizes(table, np.array([5, 0, 2]))
+    assert rows == [[4, 6, 8, 8, 8], [], [5, 5]]
+
+
+# Fig. 1b's schedule s_i = 16 + ceil(1.322 i) (Example 3), cut to 25
+# rounds as in the benchmark, and at the paper's own 183 rounds
+_FIG1B = [16 + math.ceil(1.322 * i) for i in range(183)]
+
+
+def _scalar_epsilon(row, N_c, sigma, delta):
+    """The accounting rows' epsilon by the scalar accountant, one row."""
+    if not row:
+        return 0.0
+    if sigma <= 0:
+        return None
+    try:
+        eps = moments_epsilon(list(row), N_c, sigma, delta)
+    except ValueError:
+        return None
+    return None if math.isinf(eps) else eps
+
+
+@pytest.mark.parametrize("rows,N_c,sigma,delta", [
+    pytest.param([_FIG1B[:n] for n in range(26)]
+                 + [_FIG1B[:25] + [_FIG1B[24]] * k for k in (1, 7)],
+                 60_000, 8.0, 1e-5, id="fig1b_prefixes"),
+    pytest.param([_FIG1B[:n] for n in (1, 64, 120, 183)], 60_000, 8.0,
+                 1e-5, id="paper_183_rounds"),
+    pytest.param([[4, 6, 8], [4, 6], [9, 3, 7, 7], [5], [4, 6, 8],
+                  [30, 2, 2, 2, 2, 2]], 300, 2.0, 1e-5, id="ragged"),
+    pytest.param([[64], [8, 8]], 100, 0.3, 1e-9, id="below_regime"),
+    pytest.param([[4, 6], [], [4]], 300, 0.0, 1e-5, id="sigma_zero"),
+    pytest.param([[], [], [12, 12]], 300, 2.0, 1e-5, id="empty_rows"),
+    # N_c / (s sigma) < e: the lambda cap sigma^2 ln(max(., e)) = 9 binds
+    pytest.param([[400, 600], [30], [30, 600, 30]], 1000, 3.0, 1e-5,
+                 id="lambda_cap_binds"),
+])
+def test_per_client_accounting_equals_scalar_oracle(rows, N_c, sigma,
+                                                    delta):
+    """Every epsilon the vectorised accountant reports is, bit for bit,
+    moments_epsilon of the client's own row."""
+    got = per_client_accounting(rows, N_c, sigma, delta)
+    assert [r["epsilon"] for r in got] == [
+        _scalar_epsilon(r, N_c, sigma, delta) for r in rows]
+    assert [r["samples"] for r in got] == [sum(r) for r in rows]
+    assert [r["rounds_contributed"] for r in got] == [len(r) for r in rows]
+
+
+def test_per_client_accounting_explicit_r0():
+    rows = [[4, 6, 8], [4, 6], [4, 6, 8]]
+    got = per_client_accounting(rows, 300, 4.0, 1e-5, r0=1 / math.e)
+    want = [moments_epsilon(r, 300, 4.0, 1e-5, r0=1 / math.e) for r in rows]
+    assert [r["epsilon"] for r in got] == want
+    # r0 = 1/e is looser than the default, max s / N_c * sigma
+    default = per_client_accounting(rows, 300, 4.0, 1e-5)
+    assert all(g["epsilon"] > d["epsilon"] for g, d in zip(got, default))
+
+
+@pytest.mark.parametrize("schedules,counts,N_c,sigma", [
+    pytest.param(np.array([_FIG1B[:25]] * 34), list(range(34)), 60_000,
+                 8.0, id="fig1b_table"),
+    # sizes past a client's count must not move its r0 or its lambda cap
+    pytest.param([[30, 30, 600], [30, 30, 600], [30, 30, 600], [30],
+                  [600, 30]], [1, 2, 5, 3, 2], 1000, 3.0,
+                 id="ragged_cap_past_count"),
+    pytest.param(np.array([[4, 6, 8], [8, 6, 4], [4, 6, 8]]), [3, 3, 0],
+                 300, 2.0, id="table_rows_differ"),
+])
+def test_keys_accounting_equals_scalar_oracle(schedules, counts, N_c,
+                                              sigma):
+    """The report's path, (schedule, count) keys, against the scalar
+    accountant over the sizes each client sent (the last size of a
+    schedule repeats past its end)."""
+    rows = [[int(s[min(i, len(s) - 1)]) for i in range(n)]
+            for s, n in zip(schedules, counts)]
+    got = keys_accounting(*participation_keys(schedules, counts), N_c,
+                          sigma, 1e-5)
+    assert [r["epsilon"] for r in got] == [
+        _scalar_epsilon(r, N_c, sigma, 1e-5) for r in rows]
+    assert [r["samples"] for r in got] == [sum(r) for r in rows]
+
+
 def test_dp_rows_in_engine_reports():
     task = _task(dp_clip=1.0, dp_sigma=2.0)
     kw = dict(n_clients=4, sizes_per_client=[4, 6],
@@ -210,6 +296,34 @@ def test_dp_rows_in_engine_reports():
     # no-DP runs carry no accounting rows
     r_plain = DeviceCohortSimulator(_task(), **kw).run(max_rounds=2)
     assert r_plain["telemetry"].dp is None
+
+
+@pytest.mark.parametrize("ragged", [False, True], ids=["shared", "ragged"])
+def test_dp_accounting_span_counts_keys(ragged):
+    """``cohort.dp_accounting`` nests in ``cohort.report`` and carries
+    the distinct keys the accountant evaluated and the clients charged:
+    on one schedule a key per distinct participation count, on per-client
+    schedules a key per distinct (schedule row, count)."""
+    C = 12
+    sizes = [[4, 6, 8], [5], [4, 6]] * 4 if ragged else [4, 6, 8]
+    sim = DeviceCohortSimulator(
+        _task(dp_clip=1.0, dp_sigma=2.0), n_clients=C,
+        sizes_per_client=sizes, round_stepsizes=[0.1, 0.08, 0.06], d=2,
+        seed=3, block=4, scenario="mobile_diurnal")
+    rep = sim.run(max_rounds=6)["telemetry"]
+    spans = sim.engine.timer.spans
+    (acc,) = [s for s in spans if s["name"] == "cohort.dp_accounting"]
+    (report,) = [s for s in spans if s["name"] == "cohort.report"]
+    assert report["t0"] <= acc["t0"]
+    assert acc["t0"] + acc["dur"] <= report["t0"] + report["dur"] + 1e-9
+    part = [int(p) for p in rep.participation]
+    if ragged:
+        keys = {(tuple(sim.engine.sizes[c]), part[c]) for c in range(C)}
+        assert len(keys) > len({tuple(r) for r in sim.engine.sizes})
+    else:
+        keys = set(part)
+    assert len(keys) > 1                # the scenario spreads the counts
+    assert acc["args"] == {"keys": len(keys), "clients": C}
 
 
 # --- JSONL traces -----------------------------------------------------------
